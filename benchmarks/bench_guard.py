@@ -617,22 +617,23 @@ def bench_fleet_scaling(n: int) -> dict:
     import io
     import shutil
 
+    from benchmarks._common import fleet_refusal
     from spatialflink_tpu.driver import main as driver_main
     from spatialflink_tpu.runtime import fleet as fleet_mod
     from spatialflink_tpu.streams.synthetic import clustered_lines
 
+    refused = fleet_refusal("fleet_scaling")
+    if refused:
+        return refused
     n = 30_000  # pinned: the overhead ratio mixes fixed (spawn) and
     # per-record (routing) cost, so the ceiling needs a fixed workload
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     conf = os.path.join(root, "conf", "spatialflink-conf.yml")
     lines = clustered_lines(_grid(), n, 0.95, seed=7, fmt="geojson",
                             dt_ms=1)
+    # workers are fresh processes: the warm runs below warm the measured
+    # ones through the driver's checkout compile cache
     td = tempfile.mkdtemp(prefix="bench-fleet-")
-    # workers are fresh processes: without a persistent compile cache the
-    # warm runs below could not actually warm the measured ones
-    os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
-                          os.path.join(td, "xla-cache"))
-    os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
     try:
         path1 = os.path.join(td, "in.geojson")
         with open(path1, "w") as f:
@@ -698,10 +699,14 @@ def bench_fleet_rescale(n: int) -> dict:
     import contextlib
     import shutil
 
+    from benchmarks._common import fleet_refusal
     from spatialflink_tpu.driver import main as driver_main
     from spatialflink_tpu.runtime import fleet as fleet_mod
     from spatialflink_tpu.streams.synthetic import clustered_lines
 
+    refused = fleet_refusal("fleet_rescale")
+    if refused:
+        return refused
     n = 12_000  # pinned: spawn cost (two extra workers mid-run) is fixed,
     # routing cost is per-record — the ceiling needs a fixed workload
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -709,9 +714,6 @@ def bench_fleet_rescale(n: int) -> dict:
     lines = clustered_lines(_grid(), n, 0.95, seed=7, fmt="geojson",
                             dt_ms=1)
     td = tempfile.mkdtemp(prefix="bench-rescale-")
-    os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
-                          os.path.join(td, "xla-cache"))
-    os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
     try:
         path1 = os.path.join(td, "in.geojson")
         with open(path1, "w") as f:
@@ -838,9 +840,6 @@ def main() -> int:
                          % MARGIN)
     args = ap.parse_args()
 
-    from benchmarks._common import settle_backend
-
-    settle_backend()
     import jax
 
     backend = jax.default_backend()

@@ -36,7 +36,6 @@ import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from benchmarks._common import settle_backend  # noqa: E402
 
 
 def _rows(n: int):
@@ -68,7 +67,6 @@ def main() -> int:
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
 
-    settle_backend()
     import jax
 
     from spatialflink_tpu import driver as drv

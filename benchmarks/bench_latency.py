@@ -205,9 +205,6 @@ def main() -> int:
                     help="refuse to measure on any other backend (exit 2)")
     args = ap.parse_args()
 
-    from benchmarks._common import settle_backend
-
-    settle_backend()
     import jax
 
     from spatialflink_tpu.utils import deviceplane

@@ -26,7 +26,6 @@ import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from benchmarks._common import settle_backend  # noqa: E402
 from benchmarks.bench_configs import _grid, _points, _slope_time  # noqa: E402
 
 RADIUS = 0.5
@@ -42,7 +41,6 @@ def main() -> int:
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
 
-    settle_backend()
     import jax
     import jax.numpy as jnp
 

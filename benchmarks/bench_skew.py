@@ -236,9 +236,6 @@ def main() -> int:
             os.environ["XLA_FLAGS"] = (
                 flags + " --xla_force_host_platform_device_count=8").strip()
 
-    from benchmarks._common import settle_backend
-
-    settle_backend()
     import jax
 
     backend = jax.default_backend()

@@ -1,4 +1,4 @@
-"""A/B the join lattice precisions on-chip (TPU_NOTES §7 experiment 5):
+"""A/B the join lattice precisions on-chip:
 
 - f32: `join_mask` — `Precision.HIGHEST`, three bf16 MXU passes;
 - bf16: `join_mask_bf16_superset` — single pass + margin (the decision
@@ -20,7 +20,6 @@ import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from benchmarks._common import settle_backend  # noqa: E402
 from benchmarks.bench_configs import _grid, _points, _slope_time  # noqa: E402
 
 RADIUS = 0.5
@@ -32,7 +31,6 @@ def main() -> int:
     ap.add_argument("--nb", type=int, default=1_024)
     args = ap.parse_args()
 
-    settle_backend()
     import jax
     import jax.numpy as jnp
 

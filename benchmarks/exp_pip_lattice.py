@@ -15,7 +15,7 @@ kernel's (TP, 1) columns, so lanes stay busy).
 Run on the chip:  python benchmarks/exp_pip_lattice.py [--scale full]
 Correctness (CPU): SPATIALFLINK_PALLAS=interpret python benchmarks/exp_pip_lattice.py --check
 NOT wired into the library: promotion requires an on-chip win vs the XLA
-twin (see benchmarks/TPU_NOTES.md §6 for the pip_dist precedent).
+twin.
 """
 
 from __future__ import annotations

@@ -148,9 +148,6 @@ def main() -> int:
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
 
-    from benchmarks._common import settle_backend
-
-    settle_backend()
     import jax
 
     backend = jax.default_backend()

@@ -5,20 +5,15 @@ available device (`QueryConfiguration(devices=N)`), and shows the outputs
 match bit-for-bit — the per-shard top-k partials are re-merged with an
 all-gather tree instead of the reference's parallelism-1 `windowAll` stage.
 
-With fewer than 2 real devices (or an unreachable accelerator) the demo
-arranges an 8-virtual-device CPU mesh by itself.
-
 Run: python examples/distributed_knn.py
+(on the CPU, ``JAX_PLATFORMS=cpu
+XLA_FLAGS=--xla_force_host_platform_device_count=8`` gives an 8-device mesh)
 """
 
 import os
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-from examples._common import ensure_backend
-
-ensure_backend(min_devices=8)
 
 import jax
 import numpy as np

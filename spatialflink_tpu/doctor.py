@@ -5,9 +5,9 @@
 BEFORE and AFTER a run:
 
 - ``--preflight [--require-backend tpu]`` — readiness check for the
-  accelerator: backend provenance vs the required target (the BENCH r05
-  silent-CPU-fallback condition exits non-zero instead of being discovered
-  in a ledger tail), device visibility, memory-stats availability, a tiny
+  accelerator: backend provenance vs the required target (a run that
+  would land on the CPU exits non-zero instead of being discovered in a
+  ledger tail), device visibility, memory-stats availability, a tiny
   instrumented-jit probe compile (proves the compile path + registry), and
   the persistent compilation-cache configuration. Exit 0 = ready.
 - ``summarize BUNDLE`` — one human digest of a post-mortem bundle: dump

@@ -26,7 +26,7 @@ import os
 import sys
 import time
 from dataclasses import dataclass
-from typing import Iterable, Iterator, List, Optional
+from typing import Iterable, Iterator, List, Optional, Tuple
 
 import numpy as np
 
@@ -1015,33 +1015,34 @@ def _emit(result, sink) -> None:
         sink.emit(result)
 
 
-def _enable_compilation_cache() -> None:
-    """Persist XLA compilations across CLI invocations.
+#: the checkout-local compile cache (listed in .gitignore): a fixed path, so
+#: every process of a checkout — CLI runs, fleet workers, chip_smoke.py —
+#: finds the others' compilations
+CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache")
+
+
+def enable_compilation_cache() -> Tuple[Optional[str], Optional[str]]:
+    """Persist XLA compilations across processes. -> (cache dir, error).
 
     A pipeline's kernels are identical run to run, but every fresh process
-    pays the compiles again — ~0.4 s on CPU and tens of seconds on TPU
-    (where the first jit is 20-40 s). Defaults to a user cache dir; an
-    explicit ``JAX_COMPILATION_CACHE_DIR`` (or pre-set jax config) wins.
-    Failure is non-fatal: the cache is an optimization, not a dependency.
+    pays the compiles again. ``JAX_COMPILATION_CACHE_DIR``, when set, is
+    the cache and no other directory is set in code; otherwise the cache is
+    :data:`CHECKOUT_CACHE_DIR`. Failure is non-fatal (the cache is an
+    optimization, not a dependency) and is returned and printed.
     """
     import jax
 
+    cache = os.environ.get("JAX_COMPILATION_CACHE_DIR") or CHECKOUT_CACHE_DIR
     try:
-        if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
-            cache = os.environ["JAX_COMPILATION_CACHE_DIR"]
-        elif jax.config.jax_compilation_cache_dir:
-            return  # user already configured it in-process
-        else:
-            cache = os.path.join(
-                os.environ.get("XDG_CACHE_HOME",
-                               os.path.expanduser("~/.cache")),
-                "spatialflink_tpu", "jax_cache")
         os.makedirs(cache, exist_ok=True)
         jax.config.update("jax_compilation_cache_dir", cache)
         if "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS" not in os.environ:
             jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    except Exception as e:  # pragma: no cover - depends on fs/env
+    except Exception as e:  # depends on fs/env
         print(f"note: compilation cache disabled ({e})", file=sys.stderr)
+        return None, f"{type(e).__name__}: {e}"
+    return cache, None
 
 
 def _parse_fn(cfg: StreamConfig, grid: UniformGrid, geometry: str):
@@ -1931,7 +1932,7 @@ def main(argv: Optional[List[str]] = None) -> int:
                     help=argparse.SUPPRESS)  # chaos glue, supervisor-set
     args = ap.parse_args(argv)
 
-    _enable_compilation_cache()
+    enable_compilation_cache()
     params = Params.from_yaml(args.config)
     if args.option is not None:
         params.query.option = args.option

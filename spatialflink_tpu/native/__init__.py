@@ -1,7 +1,8 @@
 """Build + ctypes bindings for the native ingest library.
 
 The shared object is compiled on first use with the system g++ (cached next
-to the source, keyed by source mtime) — no build system, no install step.
+to the source, keyed by a hash of the source's contents) — no build system,
+no install step.
 Everything degrades gracefully: ``lib()`` returns None when no compiler is
 available and callers fall back to the pure-Python parsers.
 """
@@ -9,6 +10,7 @@ available and callers fall back to the pure-Python parsers.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import sys
@@ -19,13 +21,19 @@ import platform as _platform
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "ingest.cpp")
-# cache key includes OS + arch so a binary from a foreign machine is never
-# picked up (the .so files are gitignored, this guards stale copies)
-_SO = os.path.join(
-    _DIR,
-    f"_ingest_{sys.platform}_{_platform.machine()}"
-    f"_py{sys.version_info[0]}{sys.version_info[1]}.so",
-)
+
+
+def _so_path() -> str:
+    """The library built from THIS ingest.cpp on this OS + arch: keyed by
+    the source's contents, so a binary copied along with a checkout (the
+    .so files are gitignored) is used only if it was built from the
+    committed source — never on mtime alone."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(
+        _DIR, f"_ingest_{sys.platform}_{_platform.machine()}"
+              f"_py{sys.version_info[0]}{sys.version_info[1]}_{digest}.so")
+
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
@@ -38,10 +46,11 @@ _c_int32_p = ctypes.POINTER(ctypes.c_int32)
 _c_long_p = ctypes.POINTER(ctypes.c_long)
 
 
-def _build() -> bool:
-    if os.path.exists(_SO) and os.path.getmtime(_SO) >= os.path.getmtime(_SRC):
+def _build(so: str) -> bool:
+    if os.path.exists(so):
         return True
-    cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-o", _SO + ".tmp", _SRC]
+    tmp = f"{so}.{os.getpid()}.tmp"  # concurrent builders never share it
+    cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-o", tmp, _SRC]
     try:
         r = subprocess.run(cmd, capture_output=True, timeout=120)
     except (OSError, subprocess.TimeoutExpired):
@@ -49,7 +58,7 @@ def _build() -> bool:
     if r.returncode != 0:
         sys.stderr.write(f"native ingest build failed:\n{r.stderr.decode()[-2000:]}\n")
         return False
-    os.replace(_SO + ".tmp", _SO)
+    os.replace(tmp, so)
     return True
 
 
@@ -105,22 +114,23 @@ def lib() -> Optional[ctypes.CDLL]:
     with _lock:
         if _lib is not None or _failed:
             return _lib
-        if not _build():
+        so = _so_path()
+        if not _build(so):
             _failed = True
             return None
         try:
-            _lib = _bind(ctypes.CDLL(_SO))
+            _lib = _bind(ctypes.CDLL(so))
         except OSError:
             # stale/corrupt binary: drop it and rebuild once from source
             try:
-                os.remove(_SO)
+                os.remove(so)
             except OSError:
                 pass
-            if not _build():
+            if not _build(so):
                 _failed = True
                 return None
             try:
-                _lib = _bind(ctypes.CDLL(_SO))
+                _lib = _bind(ctypes.CDLL(so))
             except OSError:
                 _failed = True
                 return None

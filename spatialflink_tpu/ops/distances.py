@@ -89,7 +89,7 @@ def point_segment_dist2(px, py, x1, y1, x2, y2):
     # per-point work below is multiply/add (measured +15% on config 4's CPU
     # bench; the divide is costlier still on the TPU VPU). A divide-free
     # cross-product form of the point_in_rings ray test was ALSO tried and
-    # measured 25% SLOWER on CPU — see benchmarks/TPU_NOTES.md §5.
+    # measured 25% SLOWER on CPU.
     inv_len = jnp.where(len_sq > 0, 1.0 / jnp.where(len_sq > 0, len_sq, 1.0),
                         0.0)
     dot = (px - x1) * cx + (py - y1) * cy

@@ -247,7 +247,7 @@ def range_points_to_geom_queries(points: PointBatch, queries: EdgeGeomBatch,
     single-query path uses the static-``is_areal`` single-geom kernel, so
     ``run()`` and ``run_multi()`` may disagree on radius-BOUNDARY records
     in the last ulp on TPU (different reduction orders); CPU parity tests
-    cannot observe this. TPU_NOTES §7 carries the on-chip parity check."""
+    cannot observe this; the on-chip parity check is not measured."""
     from spatialflink_tpu.ops.range import range_filter_masks_stats
 
     if approximate:

@@ -250,8 +250,8 @@ def _lattice_strategy() -> str:
     re-check computes dx^2+dy^2 directly, which is slightly MORE accurate
     than the f32 lattice's a2+b2-2ab expansion; a pair whose true distance
     equals r to the last ulp can differ between strategies, measure-zero on
-    real streams) at ~3x the lattice rate on TPU (to be measured; see
-    benchmarks/TPU_NOTES.md §7). Env-switched so the bench can A/B it
+    real streams) at a hoped-for ~3x the lattice rate on TPU (not
+    measured). Env-switched so the bench can A/B it
     without threading a parameter through every join operator."""
     import os
 

@@ -8,12 +8,10 @@ join reduction.
   ``range/PointPolygonRangeQuery.java:117-``, ``tRange/PointPolygonTRangeQuery
   .java:53-87`` — there a per-tuple JTS call; here one kernel per window).
   The pallas kernel is LANE-MAJOR: points tiled (128, 128) across the full
-  VPU register file, edges broadcast one at a time from SMEM scalars.
-  Measured on the chip (TPU v5e-1, 1M points x 64-edge polygon, slope
-  method, benchmarks/TPU_NOTES.md §6): 435 us/window vs 773 us for the
-  fused XLA twin (1.8x) and vs 5.25 ms for the round-3 column-major pallas
-  layout (12x) — (TP, 1) column blocks use 1 of 128 vector lanes, which is
-  why the old kernel lost to XLA despite identical arithmetic.
+  VPU register file, edges broadcast one at a time from SMEM scalars
+  ((TP, 1) column blocks would use 1 of 128 vector lanes). Its speed
+  against the fused XLA twin is not measured on today's chip;
+  ``chip_smoke.py`` runs it through the driver and checks its answers.
 - :func:`join_reduce` — per-left-point reduction over the whole right batch:
   number of right partners within radius (after Chebyshev cell pruning,
   ``join/JoinQuery.java:148-162`` semantics) plus the nearest partner's
@@ -22,12 +20,9 @@ join reduction.
   path: ``ops.join.join_pairs_host`` (every join operator's pair extraction)
   uses it to prefilter the a side when the window's lattice exceeds the
   budget, so sparse big-window joins only materialize rows that have
-  partners. This one is deliberately NOT pallas: the XLA scan runs the
-  262k x 4k reduction in 3.7 ms (288G pair-tests/s, VPU-saturating) vs
-  51 ms for the round-3 pallas version — the compiler already emits the
-  optimal code for an elementwise broadcast reduction, so the hand kernel
-  was deleted rather than carried as a showpiece (measurements in
-  benchmarks/TPU_NOTES.md §6).
+  partners. This one is deliberately NOT pallas: the compiler already
+  emits good code for an elementwise broadcast reduction, so the hand
+  kernel was deleted rather than carried as a showpiece.
 
 :func:`pip_dist` dispatch is by backend — pallas on TPU, the jnp twin
 (:func:`ops.geom.points_to_single_edges_raw`) elsewhere — overridable with

@@ -83,15 +83,7 @@ def init_distributed(coordinator_address: Optional[str] = None,
     with no coordinator configured (local dev / tests)."""
     import jax.distributed as jd
 
-    # jax < 0.5 has no jax.distributed.is_initialized(); the global client
-    # handle is the same signal
-    if hasattr(jd, "is_initialized"):
-        initialized = jd.is_initialized()
-    else:
-        from jax._src.distributed import global_state
-
-        initialized = global_state.client is not None
-    if initialized:
+    if jd.is_initialized():
         return
     if coordinator_address is None and "JAX_COORDINATOR_ADDRESS" not in os.environ:
         return  # single-process mode

@@ -46,35 +46,6 @@ from spatialflink_tpu.ops.knn import KnnResult, knn_point, topk_by_distance
 from spatialflink_tpu.ops.range import range_filter_point
 from spatialflink_tpu.parallel.mesh import CELL_AXIS, DCN_AXIS
 
-def _compat_shard_map():
-    """jax.shard_map across jax versions: < 0.5 ships it under
-    experimental, and the replication-check kwarg was named check_rep
-    before the check_vma rename — keyed on the actual signature, not the
-    attribute location, so the middle range (top-level fn, old kwarg) works
-    too."""
-    import functools
-    import inspect
-
-    fn = getattr(jax, "shard_map", None)
-    if fn is None:
-        from jax.experimental.shard_map import shard_map as fn
-    try:
-        if "check_vma" in inspect.signature(fn).parameters:
-            return fn
-    except (TypeError, ValueError):  # uninspectable: assume current API
-        return fn
-
-    @functools.wraps(fn)
-    def renamed(*args, **kwargs):
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return fn(*args, **kwargs)
-
-    return renamed
-
-
-shard_map = _compat_shard_map()
-
 
 def distributed_knn(
     mesh: Mesh,
@@ -144,7 +115,7 @@ def distributed_knn_hierarchical(
         # distributed_stream_knn's 2-D path
         return _gather_topk(_gather_topk(local, CELL_AXIS, k), DCN_AXIS, k)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         per_shard,
         mesh=mesh,
         check_vma=False,
@@ -177,7 +148,7 @@ def distributed_range_count(
         count = jax.lax.psum(jnp.sum(mask, dtype=jnp.int32), CELL_AXIS)
         return count, mask
 
-    fn = shard_map(
+    fn = jax.shard_map(
         per_shard,
         mesh=mesh,
         check_vma=False,
@@ -255,7 +226,7 @@ def _stream_filter_impl(mesh: Mesh, batch, stats_fn, mask_spec):
         mask, gn, evals = stats_fn(b)
         return (mask, jax.lax.psum(gn, axes), jax.lax.psum(evals, axes))
 
-    fn = shard_map(
+    fn = jax.shard_map(
         per_shard,
         mesh=mesh,
         check_vma=False,
@@ -323,7 +294,7 @@ def _stream_knn_impl(mesh: Mesh, batch, local_fn, k: int, gather):
             merged = gather(merged, DCN_AXIS, k)
         return merged, jax.lax.psum(n_elig, axes)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         per_shard,
         mesh=mesh,
         check_vma=False,
@@ -372,7 +343,7 @@ def distributed_stream_join_lattice(mesh: Mesh, a, b, lattice_fn):
         return lattice_fn(a_shard, b_rep)
 
     axes = _point_axes(mesh)
-    fn = shard_map(
+    fn = jax.shard_map(
         per_shard,
         mesh=mesh,
         check_vma=False,
@@ -401,7 +372,7 @@ def distributed_join_counts(
         total = jax.lax.psum(jnp.sum(per_a), CELL_AXIS)
         return per_a, total
 
-    fn = shard_map(
+    fn = jax.shard_map(
         per_shard,
         mesh=mesh,
         check_vma=False,
@@ -461,7 +432,7 @@ def distributed_taggregate(mesh: Mesh, batch, *, num_cells: int, agg: str):
 
     out_spec = (TAggregateGroups(P(), P(), P(), P())
                 if agg == "ALL" else P())
-    fn = shard_map(
+    fn = jax.shard_map(
         per_shard,
         mesh=mesh,
         check_vma=False,
@@ -490,7 +461,7 @@ def distributed_tstats_window(mesh: Mesh, batch, *, m: int):
         tabs = jax.tree.map(lambda x: _gather_shard_major(x, axes), s)
         return tstats_stitch_summaries(tabs)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         per_shard,
         mesh=mesh,
         check_vma=False,

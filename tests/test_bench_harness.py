@@ -129,3 +129,19 @@ def test_bench_e2e_smoke(tmp_path):
     assert rows[4]["speedup_vs_sequential_jobs"] > 0
     table = json.loads(out_path.read_text())
     assert table["rows"] and table["backend"] == "cpu"
+
+
+def test_fleet_rows_refuse_on_an_accelerator(monkeypatch, capsys):
+    """Fleet rows spawn driver workers while the harness holds the chip: on
+    an accelerator backend they refuse with a message instead of hanging;
+    on the CPU they run."""
+    import jax
+
+    sys.path.insert(0, _ROOT)
+    from benchmarks._common import fleet_refusal
+
+    assert fleet_refusal("fleet") is None
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    row = fleet_refusal("fleet")
+    assert row["path"] == "fleet" and "one process per chip" in row["refused"]
+    assert "refused on tpu" in capsys.readouterr().err

@@ -413,31 +413,41 @@ def test_cli_mesh_validation_after_overrides(tmp_path):
 
 
 def test_cli_enables_compilation_cache(tmp_path, monkeypatch):
-    """The CLI persists XLA compilations to a user cache dir (big win on
-    TPU where first-jit is 20-40s) — unless the user already set one."""
+    """The CLI keeps XLA compilations where ``JAX_COMPILATION_CACHE_DIR``
+    says, and otherwise at the fixed ``<checkout>/.jax_cache`` — never a
+    per-user or per-run directory, which a fresh machine would miss."""
+    import os
+
     import jax
 
-    from spatialflink_tpu.driver import _enable_compilation_cache
+    from spatialflink_tpu import driver
 
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(
+        driver.__file__)))
+    assert driver.CHECKOUT_CACHE_DIR == os.path.join(checkout, ".jax_cache")
     prev = jax.config.jax_compilation_cache_dir
     try:
         monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
-        monkeypatch.setenv("XDG_CACHE_HOME", str(tmp_path))
-        jax.config.update("jax_compilation_cache_dir", None)
-        _enable_compilation_cache()
-        want = str(tmp_path / "spatialflink_tpu" / "jax_cache")
-        assert jax.config.jax_compilation_cache_dir == want
-        assert (tmp_path / "spatialflink_tpu" / "jax_cache").is_dir()
+        monkeypatch.setenv("XDG_CACHE_HOME", str(tmp_path / "xdg"))
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path / "pre"))
+        assert driver.enable_compilation_cache() == (
+            driver.CHECKOUT_CACHE_DIR, None)
+        assert jax.config.jax_compilation_cache_dir == \
+            driver.CHECKOUT_CACHE_DIR
+        assert os.path.isdir(driver.CHECKOUT_CACHE_DIR)
+        assert not (tmp_path / "xdg").exists()
 
         # an explicit env var wins over the default
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "own"))
-        _enable_compilation_cache()
+        assert driver.enable_compilation_cache() == (
+            str(tmp_path / "own"), None)
         assert jax.config.jax_compilation_cache_dir == str(tmp_path / "own")
 
-        # a pre-set in-process config is left alone
-        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
-        jax.config.update("jax_compilation_cache_dir", str(tmp_path / "pre"))
-        _enable_compilation_cache()
-        assert jax.config.jax_compilation_cache_dir == str(tmp_path / "pre")
+        # a cache that cannot be made is reported, not fatal
+        (tmp_path / "file").write_text("")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                           str(tmp_path / "file" / "sub"))
+        cache, err = driver.enable_compilation_cache()
+        assert cache is None and err
     finally:
         jax.config.update("jax_compilation_cache_dir", prev)

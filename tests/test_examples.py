@@ -13,7 +13,7 @@ _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 def _run(script: str) -> str:
     env = dict(os.environ)
-    env["SPATIALFLINK_EXAMPLE_PLATFORM"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     flags = env.get("XLA_FLAGS", "")
     if "host_platform_device_count" not in flags:
         env["XLA_FLAGS"] = (

@@ -791,3 +791,25 @@ def test_fleet_supervisor_sigterm_drains_workers(tmp_path):
     assert proc.returncode == 0, out.decode()[-2000:]
     result = _result(fdir)
     assert result["graceful"] is True
+
+
+def test_fleet_supervisor_initialises_no_backend(tmp_path):
+    """One process per chip: on a TPU host every worker needs its own chip,
+    so the ``--fleet`` supervisor must never take one — it routes, merges
+    and supervises without initialising a JAX backend."""
+    cfg = _conf_file(tmp_path)
+    path1 = _write_input(tmp_path, _lines(n_traj=2, steps=20))
+    code = ("import sys\n"
+            "from jax._src import xla_bridge\n"
+            "from spatialflink_tpu.driver import main\n"
+            "rc = main(sys.argv[1:])\n"
+            "assert not xla_bridge.backends_are_initialized(), "
+            "'the fleet supervisor initialised a JAX backend'\n"
+            "sys.exit(rc)\n")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    r = subprocess.run(
+        [sys.executable, "-c", code]
+        + _fleet_argv(cfg, path1, tmp_path / "fleet", 1),
+        cwd=root, capture_output=True, text=True, timeout=300)
+    assert r.returncode == 0, r.stderr[-3000:]
+    assert _result(tmp_path / "fleet")["merged_windows"] > 0

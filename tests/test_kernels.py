@@ -584,8 +584,7 @@ class TestTopkStrategies:
 
     def test_auto_dispatches_partialreduce_path_on_tpu(self, monkeypatch):
         # "auto" on TPU must route large windows to the approx_verified
-        # (PartialReduce) path — the sweep-measured winner (TPU_NOTES.md) —
-        # and the result must stay exact. Backend is monkeypatched; CPU's
+        # (PartialReduce) path and the result must stay exact. Backend is monkeypatched; CPU's
         # approx_min_k fallback keeps the kernel runnable here.
         calls = []
         orig = K._topk_approx_verified

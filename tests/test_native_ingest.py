@@ -148,3 +148,21 @@ class TestBatchEnd2End:
         batch = parsed.to_batch(g)
         assert int(batch.valid.sum()) == 100
         assert (np.asarray(batch.cell)[np.asarray(batch.valid)] >= 0).all()
+
+
+def test_library_is_keyed_on_source_contents(tmp_path, monkeypatch):
+    """The loaded binary is the one built from the committed ingest.cpp: its
+    path carries the source's hash, so an edited source (or a binary copied
+    in from elsewhere) never loads by mtime alone."""
+    import hashlib
+
+    assert native.lib()._name == native._so_path()
+    src = tmp_path / "ingest.cpp"
+    monkeypatch.setattr(native, "_DIR", str(tmp_path))
+    monkeypatch.setattr(native, "_SRC", str(src))
+    src.write_text("int a;")
+    before = native._so_path()
+    src.write_text("int b;")
+    after = native._so_path()
+    assert before != after
+    assert after.endswith(hashlib.sha256(b"int b;").hexdigest()[:16] + ".so")

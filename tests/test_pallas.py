@@ -124,9 +124,8 @@ class TestPipDist:
 
 
 class TestJoinReduce:
-    """join_reduce is a tiled XLA scan (the hand pallas kernel measured 14x
-    slower on the chip and was deleted — benchmarks/TPU_NOTES.md §6); these
-    pin it to the dense NumPy oracle."""
+    """join_reduce is a tiled XLA scan (a hand pallas version was
+    deleted); these pin it to the dense NumPy oracle."""
 
     def _oracle(self, a, b, radius, layers, n):
         acx, acy = np.asarray(a.cell) // n, np.asarray(a.cell) % n

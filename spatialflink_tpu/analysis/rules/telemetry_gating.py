@@ -5,7 +5,8 @@ The telemetry contract since PR 2: with no active session the record
 loop is byte-identical — zero span/observe/record calls. The runtime
 hot-path spy proves that for the paths the tests drive; this rule proves
 the *shape* of the guarantee everywhere in the hot modules
-(``streams/*``, ``runtime/windows.py``, ``operators/base.py``): every
+(``streams/*``, ``runtime/windows.py``, ``operators/base.py``, the join's
+``operators/join_query.py`` and ``ops/join.py``, ``driver.py``): every
 method call on a session object — a value bound from
 ``telemetry.active()`` or read from a ``self._tel``-style cached field —
 must be dominated by a None-gate (enclosing ``if tel is not None:``
@@ -94,6 +95,9 @@ class TelemetryGatingRule(Rule):
     scope = ("spatialflink_tpu/streams/*.py",
              "spatialflink_tpu/runtime/windows.py",
              "spatialflink_tpu/operators/base.py",
+             "spatialflink_tpu/operators/join_query.py",
+             "spatialflink_tpu/ops/join.py",
+             "spatialflink_tpu/driver.py",
              "spatialflink_tpu/utils/accounting.py")
 
     def check(self, mod: ModuleSource,

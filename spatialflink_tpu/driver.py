@@ -147,9 +147,16 @@ class ChunkedStream:
         return self._chunks
 
     def __iter__(self):
+        from spatialflink_tpu.utils import telemetry as _telemetry
+
+        tel = _telemetry.active()
         for ch in self._chunks:
             if hasattr(ch, "parsed"):
-                recs = ch.records()
+                if tel is not None:
+                    with tel.span("materialize", query="decode"):
+                        recs = ch.records()
+                else:
+                    recs = ch.records()
                 if ch.note is not None and ch.positions is not None:
                     # flatten consumers (joins, trajectory state machines)
                     # pull one record at a time: re-note checkpoint
@@ -299,23 +306,26 @@ def decode_chunks(records: Iterable, cfg: StreamConfig, grid: UniformGrid,
     chunk_fn = chunk if callable(chunk) else None
     chunk_n = max(1, int(chunk_fn() if chunk_fn is not None else chunk))
 
+    def parse_buf() -> List:
+        if kind == "str":
+            return parse_raws(buf)
+        if kind == "obj":
+            return off_type_filter(buf)
+        return off_type_filter([parse_one(r) for r in buf])
+
     def flush():
         nonlocal buf, kind
         if not buf:
             return None
-        t0 = time.perf_counter() if tel is not None else 0.0
         if depth_gauge is not None:
             depth_gauge.set(len(buf))
-        if kind == "str":
-            out = parse_raws(buf)
-        elif kind == "obj":
-            out = off_type_filter(buf)
-        else:
-            out = off_type_filter([parse_one(r) for r in buf])
         if tel is not None:
-            # ONE ingest observe per chunk — the parse cost amortized over
+            # ONE decode span per chunk — the parse cost amortized over
             # the chunk (the scalar path observed per record)
-            tel.observe("ingest", time.perf_counter() - t0)
+            with tel.span("decode"):
+                out = parse_buf()
+        else:
+            out = parse_buf()
         meter.mark(len(buf))
         buf = []
         kind = None
@@ -2546,6 +2556,11 @@ def _run_cli(ap, args, params: Params, spec: CaseSpec, skip1: int,
             # the supervisor discovers the ephemeral port through this
             # drop file and aggregates /status + /latency into /fleet
             wctx.write_url(opserver.url)
+            if tel is not None and getattr(args, "fleet_plane",
+                                           "on") != "off":
+                # a durable copy of the ring for the harvest that comes
+                # after this opserver has closed
+                wctx.mirror_events(tel.events)
             # a harvestable first event per incarnation: the supervisor's
             # timeline shows each (re)spawn coming up before any window
             _telemetry.emit_event("worker-online", worker=wctx.worker_id,
@@ -2642,7 +2657,9 @@ def _run_cli(ap, args, params: Params, spec: CaseSpec, skip1: int,
                 wctx.note_window(result, budget=budget)
             if tel is not None:
                 s0 = time.time()
-                with tel.span("sink"):
+                meta = ({"window": result.window_start}
+                        if isinstance(result, WindowResult) else {})
+                with tel.span("sink", **meta):
                     emit_result(result)
                 s1 = time.time()
                 if isinstance(result, WindowResult):

@@ -383,7 +383,7 @@ class SpatialOperator:
     # ambiguous across sides) and apps with bespoke window logic opt OUT.
     supports_count_windows = True
 
-    #: query-family label scoping telemetry span names (``knn.kernel`` vs a
+    #: query-family label scoping telemetry span names (``knn.dispatch`` vs a
     #: flat namespace) so multi-family / --multi-query runs stay separable
     #: in one snapshot stream; subclasses set "range"/"knn"/"join"/"tknn"/…
     #: (None falls back to the class name)
@@ -1499,12 +1499,12 @@ class SpatialOperator:
         # operators in the Flink web UI, StreamingJob.java:70-72): visible
         # in a jax.profiler capture (--profile / utils.metrics.profile_to),
         # no-ops otherwise. With a telemetry session active they upgrade to
-        # stage SPANS (window/kernel/merge under the family label) which
-        # still carry the trace annotation inside; checked ONCE here so a
-        # disabled run drives the exact pre-telemetry loop.
-        op_name = type(self).__name__
+        # stage SPANS (window/dispatch/merge under the family label) which
+        # still carry the trace annotation inside, under the same names;
+        # checked ONCE here so a disabled run drives the exact
+        # pre-telemetry loop.
         tel = _telemetry.active()
-        label = self.telemetry_label or op_name
+        label = self.telemetry_label or type(self).__name__
         book = tel.traces if tel is not None else None
         costs = tel.costs if tel is not None else None
         lat = tel.latency if tel is not None else None
@@ -1556,7 +1556,7 @@ class SpatialOperator:
                 start, end, dfd, t_disp, meta = pending.popleft()
                 if tel is not None:
                     w0 = time.time()
-                    with tel.span("merge", query=label):
+                    with tel.span("merge", query=label, window=start):
                         sel = dfd.finish()
                     w1 = time.time()
                     if book is not None:
@@ -1571,7 +1571,7 @@ class SpatialOperator:
                     if not realtime or sel:
                         note_budget(start, end, meta, w0, w1)
                 else:
-                    with trace(f"{op_name}.readback"):
+                    with trace(f"{label}.merge", window=start):
                         sel = dfd.finish()
                 yield from emit(start, end, sel)
 
@@ -1591,7 +1591,9 @@ class SpatialOperator:
                 fi = self._first_ingest_ms(payload)
                 li = self._last_ingest_ms(payload) if fi is not None \
                     else None
-                with tel.span("kernel", query=label):
+                # host batch build, transfer and async launch: the kernel's
+                # own time is on the device, read from the profiler trace
+                with tel.span("dispatch", query=label, window=start):
                     sel = eval_batch(payload, start)
                 w1 = time.time()
                 if book is not None:
@@ -1608,7 +1610,7 @@ class SpatialOperator:
                 meta = (fi, li, min(t_seal, w0), w0, w1)
             else:
                 meta = None
-                with trace(f"{op_name}.dispatch"):
+                with trace(f"{label}.dispatch", window=start):
                     sel = eval_batch(payload, start)
             if isinstance(sel, Deferred):
                 if tel is not None:

@@ -26,6 +26,7 @@ from spatialflink_tpu.operators.base import (
 )
 from spatialflink_tpu.ops.join import join_pairs_host
 from spatialflink_tpu.runtime import WindowAssembler
+from spatialflink_tpu.utils import telemetry as _telemetry
 
 
 def _merge_by_time(a: Iterable[Point], b: Iterable[Point]) -> Iterator[Tuple[int, int, Point]]:
@@ -70,6 +71,22 @@ def _merge_sorted_windows(gen_a, gen_b):
             b = next(gen_b, None)
 
 
+def _spanned_windows(results: Iterator[WindowResult], tel, label: str
+                     ) -> Iterator[WindowResult]:
+    """Each pull of the next window timed as the ``<label>.window`` span,
+    closed before the window is handed on: the merge of the two streams,
+    window assembly, and every stage nested in them (decode, dispatch,
+    pair extraction)."""
+    it = iter(results)
+    while True:
+        try:
+            with tel.span("window", query=label):
+                r = next(it)
+        except StopIteration:
+            return
+        yield r
+
+
 class PointPointJoinQuery(SpatialOperator):
     telemetry_label = "join"
 
@@ -87,6 +104,9 @@ class PointPointJoinQuery(SpatialOperator):
             results = self._run_windowed_panes(ordinary, query_stream, radius)
         else:
             results = self._run_windowed(ordinary, query_stream, radius)
+        tel = _telemetry.active()
+        if tel is not None:
+            results = _spanned_windows(results, tel, self.telemetry_label)
         return self._pipeline(results)
 
     def _pipeline(self, results: Iterator[WindowResult]
@@ -436,7 +456,7 @@ class PointPointJoinQuery(SpatialOperator):
                     )
             yield WindowResult(start, end, pairs)
 
-    def _join_pairs(self, batch_a, batch_b, radius):
+    def _join_pairs(self, batch_a, batch_b, radius, window=None):
         """(a_index, b_index) survivor arrays for one window's pair lattice.
 
         Single-device: b-tiled host extraction (``ops.join.join_pairs_host``).
@@ -468,7 +488,7 @@ class PointPointJoinQuery(SpatialOperator):
                     yield ai, bi
                 return
         yield from join_pairs_host(batch_a, batch_b, radius, self.grid,
-                                   nb_layers=nb_layers)
+                                   nb_layers=nb_layers, window=window)
 
     def _batch_a(self, recs, ts_base):
         return self._point_batch(recs, ts_base)
@@ -501,17 +521,22 @@ class PointPointJoinQuery(SpatialOperator):
         # lie within one realtime window of each other are emitted
         pairs: List[Tuple[Point, Point]] = []
         if recs_a and recs_b:
-            batch_a = self._point_batch(recs_a, start)
-            batch_b = self._point_batch(recs_b, start)
-            for ai, bi in self._join_pairs(batch_a, batch_b, radius):
-                pairs.extend(
-                    (recs_a[i], recs_b[j])
-                    for i, j in zip(ai.tolist(), bi.tolist())
-                    if i < len(recs_a) and j < len(recs_b)
-                    and not (i < old_a and j < old_b)
-                    and (max_dt is None
-                         or abs(recs_a[i].timestamp - recs_b[j].timestamp) <= max_dt)
-                )
+            label = self.telemetry_label
+            with _telemetry.span("dispatch", label, window=start):
+                batch_a = self._point_batch(recs_a, start)
+                batch_b = self._point_batch(recs_b, start)
+            for ai, bi in self._join_pairs(batch_a, batch_b, radius,
+                                           window=start):
+                with _telemetry.span("pairs", label, window=start):
+                    pairs.extend(
+                        (recs_a[i], recs_b[j])
+                        for i, j in zip(ai.tolist(), bi.tolist())
+                        if i < len(recs_a) and j < len(recs_b)
+                        and not (i < old_a and j < old_b)
+                        and (max_dt is None
+                             or abs(recs_a[i].timestamp
+                                    - recs_b[j].timestamp) <= max_dt)
+                    )
         return WindowResult(start, end, pairs)
 
 

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from spatialflink_tpu.models.batches import PointBatch
+from spatialflink_tpu.utils import telemetry as _telemetry
 from spatialflink_tpu.utils.deviceplane import instrumented_jit
 from spatialflink_tpu.ops.range import cheb_layers
 
@@ -264,7 +265,7 @@ def _lattice_strategy() -> str:
 
 
 def join_pairs_host(a: PointBatch, b: PointBatch, radius, grid, tile: int = 4096,
-                    nb_layers=None, lattice_budget=None):
+                    nb_layers=None, lattice_budget=None, window=None):
     """Host-side sparse pair extraction (the actual joined output stream).
 
     Iterates b tiles, pulls each tile's boolean lattice, and yields
@@ -281,6 +282,10 @@ def join_pairs_host(a: PointBatch, b: PointBatch, radius, grid, tile: int = 4096
     ``SPATIALFLINK_JOIN_LATTICE=bf16`` swaps the per-tile lattice for the
     single-pass bf16 superset + exact f32 re-check of the survivors (same
     pairs, less MXU time on TPU).
+
+    With a telemetry session the stages are the ``join.reduce`` (pre-pass
+    launch and count readback), ``join.compact`` and ``join.lattice`` (one
+    per mask tile) spans, tagged ``window=<window>`` when it is given.
     """
     import numpy as np
 
@@ -290,6 +295,7 @@ def join_pairs_host(a: PointBatch, b: PointBatch, radius, grid, tile: int = 4096
     cx = grid.min_x + grid.cell_length * grid.n / 2
     cy = grid.min_y + grid.cell_length * grid.n / 2
     na, nb = a.x.shape[0], b.x.shape[0]
+    meta = {} if window is None else {"window": window}
     if lattice_budget is None:  # read at call time so tests can patch it
         lattice_budget = _LATTICE_BUDGET
 
@@ -306,31 +312,36 @@ def join_pairs_host(a: PointBatch, b: PointBatch, radius, grid, tile: int = 4096
         # small/zero radii). No row the lattice would keep is dropped; the
         # final pairs still come from join_mask.
         pre_r = float(np.sqrt(radius * radius + 1e-5))
-        cnt, _, _ = join_reduce(a, b, pre_r, nb_layers, n=grid.n)
-        rows = np.nonzero(np.asarray(cnt) > 0)[0]
+        with _telemetry.span("reduce", "join", **meta):
+            cnt, _, _ = join_reduce(a, b, pre_r, nb_layers, n=grid.n)
+            rows = np.nonzero(np.asarray(cnt) > 0)[0]
         if rows.size == 0:
             return
-        size = bucket_size(rows.size)
-        idx = np.concatenate(
-            [rows, np.zeros(size - rows.size, rows.dtype)])
-        sub = jax.tree.map(lambda v: np.asarray(v)[idx], a)
-        # pad slots replay row 0 — mask them out via valid
-        pad_valid = np.asarray(a.valid)[idx]
-        pad_valid[rows.size:] = False
-        sub = sub._replace(valid=pad_valid)
+        with _telemetry.span("compact", "join", **meta):
+            size = bucket_size(rows.size)
+            idx = np.concatenate(
+                [rows, np.zeros(size - rows.size, rows.dtype)])
+            sub = jax.tree.map(lambda v: np.asarray(v)[idx], a)
+            # pad slots replay row 0 — mask them out via valid
+            pad_valid = np.asarray(a.valid)[idx]
+            pad_valid[rows.size:] = False
+            sub = sub._replace(valid=pad_valid)
         for ai, bi in _tiled_pairs_host(sub, b, radius, nb_layers, cx, cy,
-                                   grid.n, tile):
+                                        grid.n, tile, meta):
             keep = ai < rows.size
             if keep.any():
                 yield rows[ai[keep]], bi[keep]
         return
 
     yield from _tiled_pairs_host(a, b, radius, nb_layers, cx, cy, grid.n,
-                                 tile)
+                                 tile, meta)
 
 
 def _tiled_pairs_host(a: PointBatch, b: PointBatch, radius, nb_layers, cx, cy,
-                 n: int, tile: int):
+                      n: int, tile: int, meta: dict):
+    """(a_index, b_index) survivors tile by tile over b; each tile's launch,
+    readback and ``nonzero`` is one ``join.lattice`` span (``meta`` rides
+    it), closed before the tile's pairs are handed on."""
     import numpy as np
 
     bf16 = _lattice_strategy() == "bf16"
@@ -343,29 +354,29 @@ def _tiled_pairs_host(a: PointBatch, b: PointBatch, radius, nb_layers, cx, cy,
     nb = b.x.shape[0]
     tile = min(tile, nb)
     for start in range(0, nb, tile):
-        b_tile = jax.tree.map(lambda v: v[start : start + tile], b)
-        if bf16:
-            m = np.asarray(join_mask_bf16_superset(
-                a, b_tile, radius, nb_layers, cx, cy, n=n))
-            ai, bi = np.nonzero(m)
-            if not ai.size:
-                continue
-            bj = bi + start
-            # exact f32 re-check on the survivors only (sparse): the
-            # superset margin admits near-boundary extras, nothing else
-            dx = axh[ai] - bxh[bj]
-            dy = ayh[ai] - byh[bj]
-            keep = (dx * dx + dy * dy).astype(np.float32) <= r2
-            ai, bj = ai[keep], bj[keep]
-            if ai.size:
-                yield ai, bj
-            continue
-        m = np.asarray(
-            join_mask(a, b_tile, radius, nb_layers, cx, cy, n=n)
-        )
-        ai, bi = np.nonzero(m)
+        with _telemetry.span("lattice", "join", **meta):
+            b_tile = jax.tree.map(lambda v: v[start : start + tile], b)
+            if bf16:
+                m = np.asarray(join_mask_bf16_superset(
+                    a, b_tile, radius, nb_layers, cx, cy, n=n))
+                ai, bj = np.nonzero(m)
+                bj += start
+                if ai.size:
+                    # exact f32 re-check on the survivors only (sparse):
+                    # the superset margin admits near-boundary extras,
+                    # nothing else
+                    dx = axh[ai] - bxh[bj]
+                    dy = ayh[ai] - byh[bj]
+                    keep = (dx * dx + dy * dy).astype(np.float32) <= r2
+                    ai, bj = ai[keep], bj[keep]
+            else:
+                m = np.asarray(
+                    join_mask(a, b_tile, radius, nb_layers, cx, cy, n=n)
+                )
+                ai, bj = np.nonzero(m)
+                bj += start
         if ai.size:
-            yield ai, bi + start
+            yield ai, bj
 
 
 def pair_min_cheb(cells_a, mask_a, cells_b, mask_b, n):

@@ -83,6 +83,9 @@ OUTBOX_FILE = "outbox.jsonl"
 HEARTBEAT_FILE = "heartbeat"
 URL_FILE = "opserver.url"
 RUNS_FILE = "runs.jsonl"
+#: the worker's own event ring, one line per event tagged with the fence
+#: of the incarnation that said it (read when its opserver is gone)
+WORKER_EVENTS_FILE = "events.jsonl"
 #: supervisor-owned files at the fleet root
 MANIFEST_FILE = "fleet.json"
 MERGED_FILE = "merged.jsonl"
@@ -719,6 +722,7 @@ class WorkerContext:
             fence=self.fence,
             gate=(stall.wedged if stall is not None else None))
         self.outbox = OutboxWriter(os.path.join(self.dir, OUTBOX_FILE))
+        self._events_f = None
 
     @staticmethod
     def from_args(args, spec) -> Optional["WorkerContext"]:
@@ -760,6 +764,26 @@ class WorkerContext:
 
     def write_url(self, url: str) -> None:
         atomic_write_json(os.path.join(self.dir, URL_FILE), {"url": url})
+
+    def mirror_events(self, ring) -> None:
+        """Append every event of ``ring`` (a telemetry ``EventRing``) to
+        ``events.jsonl``, flushed, as ``{"fence", "ev"}`` lines. A worker
+        that finishes or dies between two supervisor polls takes its
+        opserver with it; this copy is what the harvest reads then
+        (:func:`read_worker_events`)."""
+        f = open(os.path.join(self.dir, WORKER_EVENTS_FILE), "a")
+        self._events_f = f
+        fence = self.fence
+
+        def write(ev: dict) -> None:
+            try:
+                f.write(json.dumps({"fence": fence, "ev": ev},
+                                   sort_keys=True, default=str) + "\n")
+                f.flush()
+            except (OSError, ValueError):
+                pass  # closed at shutdown: the ring still has the event
+
+        ring.mirror(write)
 
     def note_window(self, result, budget: Optional[dict] = None) -> None:
         """Outbox-append one emitted window (called only for windows the
@@ -804,6 +828,32 @@ class WorkerContext:
     def close(self) -> None:
         self._heartbeat.close()
         self.outbox.close()
+        if self._events_f is not None:
+            self._events_f.close()
+
+
+def read_worker_events(workdir: str, fence: int,
+                       since: int = 0) -> List[dict]:
+    """The events incarnation ``fence`` of a worker mirrored to its
+    ``events.jsonl`` with seq past ``since``, oldest first — the
+    ``/events?since=`` answer of an opserver that is no longer there.
+    Lines of other fences (predecessors, a fenced zombie) are skipped, and
+    so is a torn last line."""
+    path = os.path.join(workdir, WORKER_EVENTS_FILE)
+    out: List[dict] = []
+    try:
+        with open(path) as f:
+            for line in f:
+                try:
+                    doc = json.loads(line)
+                    ev = doc["ev"]
+                    if int(doc["fence"]) == fence and ev["seq"] > since:
+                        out.append(ev)
+                except (ValueError, KeyError, TypeError):
+                    continue
+    except OSError:
+        pass
+    return out
 
 
 def read_runs(workdir: str) -> List[dict]:

@@ -1292,6 +1292,12 @@ class FleetSupervisor:
             return
         payload = _http_json(f"{url}/events?since={mon.cursor(wid)}",
                              timeout=timeout)
+        if payload is None:
+            # the opserver is gone (the worker finished or died since the
+            # last poll): what it said is still in its mirrored event file
+            payload = {"events": F.read_worker_events(
+                F.worker_dir(self.root, wid),
+                fence=self.manifest.fence_of(wid), since=mon.cursor(wid))}
         mon.harvest(wid, payload)
 
     def _resolve_url(self, wid: int) -> Optional[str]:

@@ -153,6 +153,15 @@ def resequence_batch(batch: List[BrokerRecord], next_offset: int
 STARVED = object()
 
 
+def _plain_records(vals: List) -> bool:
+    """True when a poll batch can take the chunked decode whole: every
+    value a raw string, none carrying the control marker."""
+    for v in vals:
+        if not isinstance(v, str) or '"control"' in v:
+            return False
+    return True
+
+
 class KafkaSource:
     """Consumer-group iterator over a topic (reference:
     ``FlinkKafkaConsumer`` at ``StreamingJob.java:473``).
@@ -205,6 +214,28 @@ class KafkaSource:
         """Commit the group's resume point (monotone in the broker)."""
         self.broker.commit(self.topic, self.group, next_offset)
 
+    def _clean(self, batch: List, pos: int, yielded: int) -> List:
+        """A fetched batch resequenced past ``pos`` and cut to the limit:
+        empty when every record in it was already delivered."""
+        cleaned = resequence_batch(batch, pos)
+        if cleaned and self.limit is not None:
+            cleaned = cleaned[:self.limit - yielded]
+        return cleaned
+
+    def _take(self, batch: List, pos: int, yielded: int):
+        """One poll's ``(values, next_positions)`` lists, with the position
+        advanced and the lagged commit made; None when nothing is new."""
+        cleaned = self._clean(batch, pos, yielded)
+        if not cleaned:
+            return None
+        vals = [r.value for r in cleaned]
+        poss = [r.offset + 1 for r in cleaned]
+        self.position = poss[-1]
+        if self.commit_lag is not None:
+            self.broker.commit(self.topic, self.group,
+                               max(0, poss[-1] - self.commit_lag))
+        return vals, poss
+
     def iter_batches(self) -> Iterator:
         """Batched consumption for chunk-aware consumers (the commit tap's
         native decode): yields ``(values, next_positions)`` lists per poll —
@@ -236,19 +267,18 @@ class KafkaSource:
                     yield STARVED
                 time.sleep(0.01)
                 continue
-            cleaned = resequence_batch(batch, pos)
-            if not cleaned:
+            # the poll's own work (resequencing, the hand-off lists, the
+            # lagged commit) is its own span, closed before the yield
+            if tel is not None:
+                with tel.span("poll", query="kafka"):
+                    item = self._take(batch, pos, yielded)
+            else:
+                item = self._take(batch, pos, yielded)
+            if item is None:
                 continue  # all duplicates of already-delivered records
-            if self.limit is not None:
-                cleaned = cleaned[:self.limit - yielded]
-            vals = [r.value for r in cleaned]
-            poss = [r.offset + 1 for r in cleaned]
-            pos = self.position = poss[-1]
-            yielded += len(vals)
-            if self.commit_lag is not None:
-                self.broker.commit(self.topic, self.group,
-                                   max(0, pos - self.commit_lag))
-            yield vals, poss
+            pos = self.position
+            yielded += len(item[0])
+            yield item
 
     def __iter__(self) -> Iterator[Any]:
         # position starts at the group's committed offset (restart resume)
@@ -280,11 +310,13 @@ class KafkaSource:
             # records re-delivered, including from before ``pos``; the
             # window-aligned commit tap's prefix bookkeeping is unsound
             # under reordered positions, so disorder stops here
-            cleaned = resequence_batch(batch, pos)
+            if tel is not None:
+                with tel.span("poll", query="kafka"):
+                    cleaned = self._clean(batch, pos, yielded)
+            else:
+                cleaned = self._clean(batch, pos, yielded)
             if not cleaned:
                 continue  # all duplicates of already-delivered records
-            if self.limit is not None:
-                cleaned = cleaned[:self.limit - yielded]
             for rec in cleaned:
                 # position advances BEFORE the hand-off so a tap reading it
                 # right after receiving the record sees "offset past me"
@@ -676,11 +708,11 @@ class WindowCommitTap:
 
         raws: List[str] = []
         poss: List[int] = []
+        tel = self._tel
 
-        def flush():
-            if not raws:
-                return
-            t0 = time.perf_counter() if self._tel is not None else 0.0
+        def decode():
+            """The buffered records as one chunk (or record list), and the
+            stop a torn control tuple defers past them."""
             # a record with an embedded newline would shift the native
             # parser's line<->record mapping; so would any count mismatch;
             # and a record the POINT bulk parser rejects outright (e.g. a
@@ -725,10 +757,17 @@ class WindowCommitTap:
                        for obj, p in zip(chunk, poss) if obj is not None]
             raws.clear()
             poss.clear()
-            if self._tel is not None:
-                # ONE ingest observe per decoded chunk — the parse cost
-                # amortized per batch (the scalar tap observed per record)
-                self._tel.observe("ingest", time.perf_counter() - t0)
+            return out, stop
+
+        def flush():
+            if not raws:
+                return
+            # ONE decode span per chunk, closed before the hand-off
+            if tel is not None:
+                with tel.span("decode", query="kafka"):
+                    out, stop = decode()
+            else:
+                out, stop = decode()
             if out is not None and len(out):
                 yield out
             if stop is not None:
@@ -746,11 +785,11 @@ class WindowCommitTap:
                 yield from flush()
                 continue
             vals, positions = item
-            fast = True
-            for v in vals:
-                if not isinstance(v, str) or '"control"' in v:
-                    fast = False
-                    break
+            if tel is not None:
+                with tel.span("decode", query="kafka"):
+                    fast = _plain_records(vals)
+            else:
+                fast = _plain_records(vals)
             if fast:
                 # append in chunk-sized slices so the decode-chunk bound
                 # holds even when a poll batch exceeds it

@@ -239,16 +239,17 @@ def metered(stream: Iterable, meter: Meter,
 
 
 @contextlib.contextmanager
-def trace(name: str):
+def trace(name: str, **meta):
     """Named trace annotation visible in a jax.profiler capture; no-op when
-    profiling machinery is unavailable. Only the annotation SETUP is
-    guarded — an exception raised by the enclosed block must propagate
-    unchanged (a try around the yield would swallow it and break the
-    generator contract)."""
+    profiling machinery is unavailable. ``meta`` rides the event as stats
+    (``window=<start>`` ties one window's spans together). Only the
+    annotation SETUP is guarded — an exception raised by the enclosed
+    block must propagate unchanged (a try around the yield would swallow
+    it and break the generator contract)."""
     try:
         import jax.profiler as _prof
 
-        cm = _prof.TraceAnnotation(name)
+        cm = _prof.TraceAnnotation(name, **meta)
     except Exception:
         cm = None
     if cm is None:

@@ -9,8 +9,20 @@ dimensions, all host-side and all O(1) per observation:
   self (minus-children) wall-clock per named stage, nesting-aware via a
   thread-local stack, composing with :func:`~.metrics.trace` so every span
   is also a jax.profiler annotation when a ``--profile`` capture is running.
-  Stage names are query-scoped (``knn.kernel`` vs one flat namespace) so
-  ``--multi-query`` and multi-family runs stay separable.
+  Stage names are query-scoped (``knn.dispatch`` vs one flat namespace) so
+  ``--multi-query`` and multi-family runs stay separable. The served
+  broker path's stages, outermost first: ``<q>.window`` (one pull of the
+  next window; its self time is window assembly) around ``kafka.fetch``
+  (the broker call), ``kafka.poll`` (resequencing and the hand-off
+  lists), ``kafka.decode`` (the commit tap's control scan and native
+  decode; ``decode`` off the broker) and ``decode.materialize`` (per-record
+  ``Point`` objects for the flatten consumers); then ``<q>.dispatch``
+  (host batch build, transfer and async launch — not the kernel, whose
+  time is on the device), ``<q>.merge`` (the blocking readback), the
+  join's ``join.reduce`` / ``join.compact`` / ``join.lattice`` /
+  ``join.pairs`` (pre-pass and count readback, row compaction, each mask
+  tile, the pair tuples), and ``sink`` / ``kafka.sink``. No span stays
+  open across a ``yield``; the per-window ones carry ``window=<start>``.
 - :class:`StreamingHistogram` — fixed log-bucket histogram (geometric
   buckets, O(1) record, constant memory) exposing p50/p95/p99/max; the
   per-record and per-window latency distributions ride it instead of an
@@ -99,8 +111,8 @@ class SpanStats:
         self.total_s = 0.0
         self.max_s = 0.0
         #: total minus time spent in CHILD spans (the nesting-aware part:
-        #: an outer "window" span wrapping a "kernel" span reports how much
-        #: of the window was NOT kernel)
+        #: an outer "window" span wrapping a "dispatch" span reports how much
+        #: of the window was NOT dispatch)
         self.self_s = 0.0
         self.errors = 0
 
@@ -119,15 +131,16 @@ class _Span:
     a StopIteration raised INSIDE the block propagates normally — spans wrap
     ``next()`` calls on the window assembly path."""
 
-    __slots__ = ("tel", "name", "t0", "child_s", "_trace")
+    __slots__ = ("tel", "name", "meta", "t0", "child_s", "_trace")
 
-    def __init__(self, tel: "Telemetry", name: str):
+    def __init__(self, tel: "Telemetry", name: str, meta: dict):
         self.tel = tel
         self.name = name
+        self.meta = meta
         self.child_s = 0.0
 
     def __enter__(self) -> "_Span":
-        self._trace = trace(self.name)
+        self._trace = trace(self.name, **self.meta)
         self._trace.__enter__()
         self.tel._stack().append(self)
         self.t0 = time.perf_counter()
@@ -271,13 +284,20 @@ class CellOccupancy:
 
         self._np = np
         self._counts = np.zeros(0, dtype=np.int64)
+        self._grow_lock = threading.Lock()
 
     def _ensure(self, hi: int) -> None:
+        # growth is rare and locked: any thread that builds a point while
+        # a session is active records its cell, and two unlocked growths
+        # could leave the smaller array in place
         if hi > self._counts.size:
-            np = self._np
-            grown = np.zeros(max(hi, 2 * self._counts.size), dtype=np.int64)
-            grown[: self._counts.size] = self._counts
-            self._counts = grown
+            with self._grow_lock:
+                if hi > self._counts.size:
+                    np = self._np
+                    grown = np.zeros(max(hi, 2 * self._counts.size),
+                                     dtype=np.int64)
+                    grown[: self._counts.size] = self._counts
+                    self._counts = grown
 
     def record_scalar(self, ci: int) -> None:
         """One pre-validated cell id (>= 0): a bounds check + increment."""
@@ -399,6 +419,7 @@ class EventRing:
 
         self._ring = deque(maxlen=max(1, int(capacity)))
         self._lock = threading.Lock()
+        self._mirror = None
         self.total = 0
 
     def append(self, kind: str, **fields) -> dict:
@@ -409,7 +430,19 @@ class EventRing:
             self.total += 1
             ev["seq"] = self.total
             self._ring.append(ev)
+            if self._mirror is not None:
+                self._mirror(ev)
         return ev
+
+    def mirror(self, fn) -> None:
+        """Hand every event to ``fn`` in seq order — the ones already in
+        the ring at once, each later one as it is appended (under the
+        lock, so a durable copy keeps the ring's order). ``fn`` must not
+        raise."""
+        with self._lock:
+            for ev in self._ring:
+                fn(ev)
+            self._mirror = fn
 
     def list(self, since: Optional[int] = None) -> List[dict]:
         with self._lock:
@@ -621,16 +654,23 @@ class CostProfiles:
         self.tick_interval_s = max(0.01, float(tick_interval_s))
         self._last_tick_s = time.time()
         self._lock = threading.Lock()
+        self._grow_lock = threading.Lock()
 
     def _ensure(self, hi: int) -> None:
-        if hi > self._records.size:
-            np = self._np
-            size = max(hi, 2 * self._records.size)
-            for name in ("_records", "_cost_ms", "_pending"):
-                old = getattr(self, name)
-                grown = np.zeros(size, dtype=old.dtype)
-                grown[: old.size] = old
-                setattr(self, name, grown)
+        # ``_pending`` is grown last, so its size vouches for all three
+        # arrays; growth is rare and locked, so a second recording thread
+        # (one that builds points while a session is active) never sees
+        # the half-grown set
+        if hi > self._pending.size:
+            with self._grow_lock:
+                if hi > self._pending.size:
+                    np = self._np
+                    size = max(hi, 2 * self._pending.size)
+                    for name in ("_records", "_cost_ms", "_pending"):
+                        old = getattr(self, name)
+                        grown = np.zeros(size, dtype=old.dtype)
+                        grown[: old.size] = old
+                        setattr(self, name, grown)
 
     def record_scalar(self, ci: int) -> None:
         """One pre-validated cell id — the per-record ingest twin of
@@ -641,7 +681,8 @@ class CostProfiles:
         records cells — and the snapshot readers tolerate a torn read of
         one in-flight bucket by design. Taking the instance lock here
         measurably starves the drive loop against the reporter/opserver
-        tick cadence (~3x on the follow acceptance run)."""
+        tick cadence (~3x on the follow acceptance run). Only the rare
+        array growth in :meth:`_ensure` is locked."""
         self._ensure(ci + 1)
         self._records[ci] += 1
         self._pending[ci] += 1
@@ -877,11 +918,13 @@ class Telemetry:
                 st = self.spans.setdefault(name, SpanStats(name))
         return st
 
-    def span(self, stage: str, query: Optional[str] = None) -> _Span:
+    def span(self, stage: str, query: Optional[str] = None,
+             **meta) -> _Span:
         """Context manager timing one activation of ``stage``; ``query``
-        scopes the stage name (``knn.kernel``) so families/queries stay
-        separable. Exceptions propagate (and bump ``errors``)."""
-        return _Span(self, f"{query}.{stage}" if query else stage)
+        scopes the stage name (``knn.dispatch``) so families/queries stay
+        separable; ``meta`` rides the profiler annotation as stats
+        (``window=<start>``). Exceptions propagate (and bump ``errors``)."""
+        return _Span(self, f"{query}.{stage}" if query else stage, meta)
 
     def observe(self, stage: str, dt_s: float,
                 query: Optional[str] = None) -> None:
@@ -1002,12 +1045,12 @@ def set_active(tel: Optional[Telemetry]) -> Optional[Telemetry]:
     return old
 
 
-def span(stage: str, query: Optional[str] = None):
+def span(stage: str, query: Optional[str] = None, **meta):
     """Module-level convenience for call-once sites (stage boundaries, CLI
     plumbing): a real span when a session is active, a shared nullcontext
     otherwise. Per-record loops should capture :func:`active` instead."""
     tel = _ACTIVE
-    return tel.span(stage, query) if tel is not None else _NULL_CM
+    return tel.span(stage, query, **meta) if tel is not None else _NULL_CM
 
 
 def emit_event(kind: str, **fields) -> None:
@@ -1265,8 +1308,8 @@ def prometheus_text(tel: Optional[Telemetry] = None,
     gauges and registry counters as-is. Metric names are fixed; the
     span/histogram/counter name rides a label (dots and dashes are legal
     in label VALUES, so the query-scoped names survive unmangled).
-    Query-family-scoped spans and histograms (``knn.kernel``) split into
-    PROPER labels — ``stage="kernel",family="knn"`` — instead of a
+    Query-family-scoped spans and histograms (``knn.dispatch``) split into
+    PROPER labels — ``stage="dispatch",family="knn"`` — instead of a
     flattened combined value, so live scrapes can aggregate a stage
     across families (``sum by (stage)``) or a family across stages
     without regex label surgery; unscoped names render as ``stage="..."``

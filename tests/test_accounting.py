@@ -321,10 +321,11 @@ class TestDispatchAttribution:
         assert set(ten["tenants"]) == {"acme", "free"}
         assert ten["flushed"] == 0
         # conservation: attributed kernel-ms sums to the measured spans
-        # CostProfiles recorded at the same site (exact by construction)
-        total_measured = tel.costs.cells_payload()["total_kernel_ms"]
+        # CostProfiles recorded at the same site (exact by construction);
+        # read unrounded, since the payloads round each row to 3 decimals
+        total_measured = float(tel.costs._cost_ms.sum())
         total_attributed = sum(r["kernel_ms"]
-                               for r in ten["tenants"].values())
+                               for r in tel.tenants._tenants.values())
         assert total_attributed == pytest.approx(total_measured, rel=1e-6)
         assert ten["max_residual_ms"] < 1e-6
 

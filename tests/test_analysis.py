@@ -369,6 +369,20 @@ class TestTelemetryGatingRule:
             "        pass\n", self.SCOPE)
         assert "telemetry-gating" not in _ids(fs)
 
+    @pytest.mark.parametrize("path", [
+        "spatialflink_tpu/operators/join_query.py",
+        "spatialflink_tpu/ops/join.py",
+        "spatialflink_tpu/driver.py"])
+    def test_stage_span_modules_in_scope(self, path):
+        """The modules that took a session for the served path's stage
+        spans are hot modules too."""
+        src = ("from spatialflink_tpu.utils import telemetry as _t\n\n"
+               "def pull(it):\n"
+               "    tel = _t.active()\n"
+               "    with tel.span('window', query='join'):\n"
+               "        return next(it)\n")
+        assert "telemetry-gating" in _ids(check_source(src, path))
+
     def test_cold_module_out_of_scope(self):
         fs = check_source(
             "from spatialflink_tpu.utils import telemetry as _t\n\n"

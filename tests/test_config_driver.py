@@ -369,7 +369,8 @@ def test_output_file_join_pairs_are_serialized(tmp_path):
 def test_cli_profile_writes_trace_with_operator_annotations(tmp_path):
     """--profile DIR captures a jax.profiler trace of the run (SURVEY §5
     tracing ≙ the reference's Flink web UI, StreamingJob.java:70-72) with
-    per-operator dispatch/readback spans."""
+    per-operator dispatch/merge spans, named as a telemetry session
+    names them."""
     import glob
     import gzip
 
@@ -385,8 +386,8 @@ def test_cli_profile_writes_trace_with_operator_annotations(tmp_path):
                        "*.trace.json.gz"))
     assert js
     body = gzip.open(js[0], "rt", errors="replace").read()
-    assert "PointPointRangeQuery.dispatch" in body
-    assert "PointPointRangeQuery.readback" in body
+    assert "range.dispatch" in body
+    assert "range.merge" in body
 
 
 def test_cli_mesh_validation_after_overrides(tmp_path):

@@ -254,7 +254,7 @@ class TestCostProfiles:
         """Full operator drive over a clustered point stream with a
         session: the hot cell tops the cost profile AND the status digest
         surfaces it (top_cost_cells), with the family profile fed from
-        the real kernel spans."""
+        the real dispatch spans."""
         from spatialflink_tpu.streams.synthetic import clustered_points
 
         # shared generator (streams.synthetic): 70% of records in a tight

@@ -9,7 +9,8 @@ telescope); (2) the lineage sidecar is INVISIBLE to exactly-once
 identity — the merged.jsonl bytes and digest are identical with the
 plane on or off; (3) a chaos-killed worker's own events land in the
 merged timeline BEFORE its restart (the kill path harvests the dying
-worker's ring before noting the restart); (4) ``/fleet/metrics``
+worker's ring before noting the restart — from its mirrored event file
+when its opserver has already closed); (4) ``/fleet/metrics``
 federates every worker's Prometheus text under ``worker="wN"`` labels.
 """
 
@@ -356,6 +357,44 @@ def _fleet_argv(cfg, path1, fleet_dir, n, *extra):
              "--fleet", str(n), "--fleet-dir", str(fleet_dir),
              "--fleet-heartbeat", "0.25",
              "--fleet-epoch-records", "100"] + list(extra))
+
+
+def test_worker_event_mirror_read_back_by_fence(tmp_path):
+    """The worker's mirrored event file answers ``?since=`` for the
+    incarnation the fence names: events already in the ring when the
+    mirror is installed come first, a predecessor's lines and a torn tail
+    line are skipped, and appends after close are dropped quietly."""
+    from spatialflink_tpu.utils.telemetry import EventRing
+
+    old = F.WorkerContext(str(tmp_path), 0, family="range", fence=0)
+    ring0 = EventRing()
+    old.mirror_events(ring0)
+    ring0.append("worker-online", url="http://old")
+    old.close()
+
+    ctx = F.WorkerContext(str(tmp_path), 0, family="range", fence=1)
+    ring = EventRing()
+    ring.append("worker-online", url="http://new")
+    ctx.mirror_events(ring)
+    ring.append("sentinel-warm", reason="warm")
+    ring.append("checkpoint-restored", seq=3)
+    with open(os.path.join(ctx.dir, F.WORKER_EVENTS_FILE), "a") as f:
+        f.write('{"fence": 1, "ev": {"kind": "tor')
+    ctx.close()
+    ring.append("after-close")  # the mirror swallows the closed file
+
+    wd = F.worker_dir(str(tmp_path), 0)
+    got = F.read_worker_events(wd, fence=1)
+    assert [e["kind"] for e in got] == [
+        "worker-online", "sentinel-warm", "checkpoint-restored"]
+    assert [e["seq"] for e in got] == [1, 2, 3]
+    assert got[0]["url"] == "http://new"
+    assert [e["kind"] for e in F.read_worker_events(wd, fence=1, since=2)] \
+        == ["checkpoint-restored"]
+    assert [e["url"] for e in F.read_worker_events(wd, fence=0)] == \
+        ["http://old"]
+    assert F.read_worker_events(str(tmp_path / "absent"), fence=0) == []
+    assert len(ring.list()) == 4
 
 
 def _fetch_json(url, timeout=5):

@@ -114,20 +114,20 @@ class TestSpans:
 
     def test_observe_accumulates(self):
         tel = Telemetry()
-        tel.observe("ingest", 0.01)
-        tel.observe("ingest", 0.03)
-        st = tel.spans["ingest"]
+        tel.observe("decode", 0.01)
+        tel.observe("decode", 0.03)
+        st = tel.spans["decode"]
         assert st.count == 2
         assert st.total_s == pytest.approx(0.04)
         assert st.max_s == pytest.approx(0.03)
 
     def test_query_scoping_separates_families(self):
         tel = Telemetry()
-        with tel.span("kernel", query="knn"):
+        with tel.span("dispatch", query="knn"):
             pass
-        with tel.span("kernel", query="range"):
+        with tel.span("dispatch", query="range"):
             pass
-        assert {"knn.kernel", "range.kernel"} <= set(tel.spans)
+        assert {"knn.dispatch", "range.dispatch"} <= set(tel.spans)
 
 
 class TestGaugesAndOccupancy:
@@ -365,6 +365,39 @@ class TestDriverTelemetry:
         assert spy.calls == 0, \
             "telemetry disabled must leave the record loop uninstrumented"
 
+    @pytest.mark.parametrize("option", [51, 101])
+    def test_off_by_default_no_calls_on_broker_paths(
+            self, tmp_path, monkeypatch, capsys, option):
+        """The served broker path's stage spans (fetch, poll, decode,
+        materialize, window, dispatch, merge, the join's extraction, sink)
+        make no session call without a session."""
+        from spatialflink_tpu.driver import main
+        from spatialflink_tpu.ops import join as join_ops
+        from spatialflink_tpu.streams.kafka import (reset_memory_brokers,
+                                                    resolve_broker)
+
+        spy = _CallCounter(monkeypatch)
+        monkeypatch.setattr(join_ops, "_LATTICE_BUDGET", 1)  # the pre-pass
+        with open("conf/spatialflink-conf.yml") as f:
+            d = yaml.safe_load(f)
+        d["kafkaBootStrapServers"] = f"memory://tel-off-{option}"
+        cfg = tmp_path / "conf.yml"
+        cfg.write_text(yaml.safe_dump(d))
+        reset_memory_brokers()
+        try:
+            broker = resolve_broker(f"memory://tel-off-{option}")
+            with open(_write_points(tmp_path / "pts.geojson")) as f:
+                for line in f:
+                    broker.produce("points.geojson", line.strip())
+                    broker.produce("queries.geojson", line.strip())
+            assert active() is None
+            assert main(["--config", str(cfg), "--kafka", "--option",
+                         str(option)]) == 0
+        finally:
+            reset_memory_brokers()
+        assert spy.calls == 0, \
+            "telemetry disabled must leave the broker path uninstrumented"
+
     def test_status_server_idle_keeps_record_loop_identical(
             self, tmp_path, monkeypatch):
         """The live-plane hot-path guarantee: --status-port with no
@@ -406,7 +439,7 @@ class TestDriverTelemetry:
         assert len(snaps) >= 2
         last = snaps[-1]
         # the span taxonomy covers the pipeline end to end
-        assert {"ingest", "range.window", "range.kernel", "range.merge",
+        assert {"decode", "range.window", "range.dispatch", "range.merge",
                 "sink"} <= set(last["spans"])
         assert last["histograms"]["window-latency-ms"]["count"] >= 1
         assert last["grid"]["occupied_cells"] >= 1
@@ -489,8 +522,9 @@ class TestKafkaFollowAcceptance:
                 assert SNAPSHOT_KEYS <= set(s)
             last = snaps[-1]
             # stage spans across the pipeline (+ transport)
-            assert {"ingest", "range.window", "range.kernel", "range.merge",
-                    "kafka.fetch", "kafka.sink", "sink"} <= set(last["spans"])
+            assert {"kafka.poll", "kafka.decode", "range.window",
+                    "range.dispatch", "range.merge", "kafka.fetch",
+                    "kafka.sink", "sink"} <= set(last["spans"])
             # latency histogram percentiles
             wl = last["histograms"]["window-latency-ms"]
             assert wl["count"] >= 1 and "p50" in wl and "p99" in wl

@@ -206,7 +206,8 @@ class Trace:
 
     def idle_gaps(self, k: int = 10) -> list:
         """The longest stretches of the window in which the first device ran
-        nothing, each named by the host span that covered most of it."""
+        nothing, each named by the host span that covered most of it and
+        the share of the gap that span covers: ``knn.window (97.3%)``."""
         if not self.ops:
             return [["no device operations", self.window_s]]
         dev = sorted(self.ops)[0]
@@ -219,12 +220,14 @@ class Trace:
         return [[self._cover(a, b), g] for g, a, b in gaps]
 
     def _cover(self, a: float, b: float) -> str:
-        best, key = "none", (0.0, 0.0)
+        best, key = None, (0.0, 0.0)
         for s, e, n in self.spans:
             ov = min(e, b) - max(s, a)
             if ov > 0 and (ov, -(e - s)) > key:
                 best, key = n, (ov, -(e - s))
-        return best
+        if best is None:
+            return "none"
+        return f"{best} ({100 * key[0] / (b - a):.1f}%)"
 
 
 def _runs(ops, match: str):

@@ -6,6 +6,14 @@ The program receives only the generated records. Its own graceful stop ends
 the run: once the window has closed, the next fetch of an input topic hands
 the consumer GeoFlink's control tuple (``HelperClass.checkExitControlTuple``)
 in place of the next record, and ``driver.main`` unwinds and returns 0.
+
+The window closes after ``seconds``, or sooner, at the first check at which
+the primary input topic holds less than one slide of events unfetched: a
+program fast enough to drain the backlog is measured over what it drained,
+and never reaches the topic's end (and its end-of-stream flush of partial
+windows) inside the window. A program that returns inside the window has
+crashed or lost its stream, and the run fails.
+
 Everything measured is read afterwards from the output topic, whose records
 carry the broker's append time (``timestamp_ms``): the emit clock.
 """
@@ -36,7 +44,8 @@ def driver_conf(conf: dict, traffic: dict) -> dict:
 
 class Run:
     """One run of the program; ``window`` is (t_open, t_close) on the host's
-    wall clock once the window has closed."""
+    wall clock once the window has closed, and ``closed_by`` says what
+    closed it: ``"seconds"`` or ``"backlog"``."""
 
     def __init__(self, conf: dict, traffic: dict, streams, seconds: float,
                  ready, tracer=None):
@@ -52,6 +61,10 @@ class Run:
         self.broker = resolve_broker(BROKER_URL)
         self.out_topic = conf["geoflink_conf"]["outputStream"]["topicName"]
         self.window = None
+        self.closed_by = "seconds"
+        self.primary = conf["topic1"]
+        # the window closes once the primary topic holds less than this
+        self.min_backlog = conf["stream_rate_hz"] * conf["slide_s"]
         self.fetched = {s.topic: 0 for s in streams}
         self.lowered: list = []     # (wall time, program) lowered
         self._stop = threading.Event()
@@ -118,9 +131,14 @@ class Run:
                 self.tracer.start()
             t_open = time.time()
             while time.time() < t_open + self.seconds:
+                left = self.backlog()[self.primary]
                 if self._done.is_set():
-                    raise RuntimeError("the program ended inside the window "
-                                       "(backlog exhausted?)")
+                    raise RuntimeError(
+                        f"the program ended inside the window with {left} "
+                        f"records of {self.primary!r} unfetched")
+                if left < self.min_backlog:
+                    self.closed_by = "backlog"
+                    break
                 time.sleep(min(0.05, max(0.0, t_open + self.seconds
                                          - time.time())))
             t_close = time.time()

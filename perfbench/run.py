@@ -332,6 +332,8 @@ def main(argv=None) -> int:
            "lowered_in_window": run.lowered_in_window(),
            "backlog_at_open": run.backlog_at_open,
            "backlog_at_close": run.backlog_at_close,
+           "window_wall_s": run.window[1] - run.window[0],
+           "closed_by": run.closed_by,
            "answers_checked": attempted, "reference_s": t_ref,
            "notes": ctx.notes}
     print(json.dumps(aux), file=sys.stderr)

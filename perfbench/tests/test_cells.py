@@ -8,17 +8,21 @@ import json
 
 import pytest
 
+import drive
 import run as bench
-from spatialflink_tpu.streams.kafka import InMemoryBroker
+from spatialflink_tpu.streams.kafka import BrokerRecord, InMemoryBroker
 
 CELLS = ["tdrive-knn-window-drain", "tdrive-join-window-drain"]
 E2E = {"tdrive-knn-window-drain": {"events_per_s", "setup_s"},
        "tdrive-join-window-drain": {"events_per_s", "setup_s"}}
+STAGES = {"decode_ms_per_window.drain", "assembly_ms_per_window.drain",
+          "dispatch_ms_per_window.drain", "unattributed_share.drain"}
 LAYER = {"tdrive-knn-window-drain": {"device_idle_share.drain",
                                      "fetch_share.drain",
-                                     "readback_ms_per_window.drain"},
+                                     "readback_ms_per_window.drain"} | STAGES,
          "tdrive-join-window-drain": {"device_idle_share.drain",
-                                      "fetch_share.drain"}}
+                                      "fetch_share.drain",
+                                      "extract_ms_per_window.drain"} | STAGES}
 SEED = 2_147_483_999
 
 
@@ -33,6 +37,12 @@ def result(out):
     return json.loads(out.strip().splitlines()[-1])
 
 
+def aux(err):
+    """The run's auxiliary line on standard error."""
+    return json.loads(next(ln for ln in err.splitlines()
+                           if ln.startswith('{"setup_parts_s"')))
+
+
 @pytest.mark.parametrize("cell", CELLS)
 @pytest.mark.parametrize("trace", [0, 1])
 def test_rehearsal(capsys, cell, trace):
@@ -45,9 +55,60 @@ def test_rehearsal(capsys, cell, trace):
     assert set(res["metrics"]) == (LAYER if trace else E2E)[cell]
     assert list(res)[-1] == "compared"
     assert err.strip().splitlines()[-1] == "correct True"
+    assert aux(err)["closed_by"] == "seconds"
     if trace:
         assert 0 < res["device"]["busy_s"] <= res["device"]["window_s"]
         assert res["breakdown"]["device_ops"]
+
+
+def cut_backlog(monkeypatch, rate_hz, max_rate_hz):
+    """The rehearsal at ``rate_hz`` of event time with a backlog of
+    ``max_rate_hz`` x seconds past the warm-up: small enough for the
+    program to drain it inside the window, with one slide
+    (``rate_hz`` x 5 s) many polls of the window's monitor long."""
+    shrink = bench.shrink
+
+    def cut(conf, traffic):
+        shrink(conf, traffic)
+        conf["stream_rate_hz"] = rate_hz
+        traffic["max_rate_hz"] = max_rate_hz
+
+    monkeypatch.setattr(bench, "shrink", cut)
+
+
+def test_window_closes_at_the_backlog(capsys, monkeypatch):
+    cut_backlog(monkeypatch, 20_000, 300_000)
+    rc, out, err = run_cell(capsys, CELLS[0])
+    assert rc == 0, err[-3000:]
+    res = result(out)
+    assert res["correct"] is True, res["compared"]
+    assert res["attempted"] >= 2
+    assert res["metrics"]["events_per_s"]["value"] > 0
+    a = aux(err)
+    assert a["closed_by"] == "backlog"
+    assert a["window_wall_s"] < 2
+    assert 0 < a["backlog_at_close"]["taxis"] < 20_000 * 5
+
+
+def plant_lost_stream(monkeypatch, at=200_000):
+    """The taxi stream ends at offset ``at``, far short of the backlog's
+    end: the program's consumer gets the control tuple there."""
+    fetch = InMemoryBroker.fetch
+
+    def lost(self, topic, offset, max_records=500):
+        if topic == "taxis" and offset >= at:
+            return [BrokerRecord(offset=offset, key=None, value=drive.CONTROL)]
+        return fetch(self, topic, offset, max_records)
+
+    monkeypatch.setattr(InMemoryBroker, "fetch", lost)
+
+
+def test_early_end_with_backlog_left_fails(capsys, monkeypatch):
+    plant_lost_stream(monkeypatch)
+    with pytest.raises(RuntimeError,
+                       match="ended inside the window with [0-9]+ records"):
+        run_cell(capsys, CELLS[0])
+    assert capsys.readouterr().out == ""
 
 
 @pytest.mark.parametrize("cell", CELLS)

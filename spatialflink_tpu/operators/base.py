@@ -445,11 +445,15 @@ class SpatialOperator:
 
     def _shard(self, batch):
         """Place a window batch with its point dim sharded over the mesh
-        (over BOTH axes of a 2-D mesh)."""
+        (over BOTH axes of a 2-D mesh); the ``<q>.place`` span, inside the
+        window's ``<q>.dispatch``."""
         from spatialflink_tpu.parallel.mesh import shard_batch
+        from spatialflink_tpu.utils import telemetry as _telemetry
 
         mesh = self._mesh()
-        return shard_batch(batch, mesh, axis=tuple(mesh.axis_names))
+        with _telemetry.span("place", query=self.telemetry_label
+                             or type(self).__name__):
+            return shard_batch(batch, mesh, axis=tuple(mesh.axis_names))
 
     def _degrade_mesh(self, err: BaseException) -> None:
         """Elastic degraded mode (SURVEY §7 phase 7): a device failure during

@@ -3,8 +3,9 @@
 Reference: ``spatialOperators/knn/PointPointKNNQuery.java`` (two-stage
 per-cell top-k + global dedup merge). Here the whole window is one kernel:
 masked distances -> objID dedup -> top-k (ops.knn), optionally sharded over a
-mesh with an all-gather merge (parallel.ops.distributed_knn), which removes
-the reference's parallelism-1 ``windowAll`` stage.
+mesh as one compiled program with an all-gather merge
+(parallel.ops.knn_mesh_stats), which removes the reference's parallelism-1
+``windowAll`` stage.
 
 The radius argument prunes the candidate *cells* only — windowed kNN in the
 reference does not radius-filter exact distances (``:152-183``); radius 0
@@ -147,29 +148,30 @@ class PointPointKNNQuery(SpatialOperator):
 
     def _knn_result(self, batch, query_point: Point, radius: float, k: int):
         """(KnnResult, dist_evals) over one window batch — the count rides
-        the same dispatch (ops.knn.knn_point_stats single-device; a psum on
-        the mesh) and feeds the pruning counter. With ``conf.devices`` the
-        point dim is sharded and per-device dedup+top-k partials are
-        all-gathered and re-merged (parallel.ops.distributed_stream_knn) —
-        the two-stage merge of SURVEY §2.5 without the reference's
+        the same dispatch and feeds the pruning counter. Single-device:
+        ``ops.knn.knn_point_stats`` on the whole batch. With
+        ``conf.devices`` the point dim is sharded and the one compiled mesh
+        program ``parallel.ops.knn_mesh_stats`` runs the same kernel per
+        shard, all-gathers the k-sized partials and re-merges them — the
+        two-stage merge of SURVEY §2.5 without the reference's
         parallelism-1 windowAll stage."""
         nb_layers = self._nb_layers(radius)
         def local(b):
-            # ONE closure for both paths: the module-jitted kernel runs on
-            # the whole batch single-device and per shard distributed —
-            # identical fusion, bit-for-bit 8-dev ≡ 1-dev
             return knn_point_stats(
                 b, query_point.x, query_point.y,
                 jnp.int32(query_point.cell), radius, nb_layers,
                 n=self.grid.n, k=k, strategy=self._knn_strategy())
 
-        from spatialflink_tpu.parallel.ops import distributed_stream_knn
+        def on_mesh(mesh, sb):
+            from spatialflink_tpu.parallel.ops import knn_mesh_stats
 
-        return self._stream_dispatch(
-            batch, local,
-            lambda mesh, sb: distributed_stream_knn(
-                mesh, sb, k=k, strategy=self._knn_strategy(),
-                local_fn=local))
+            return knn_mesh_stats(
+                sb, query_point.x, query_point.y,
+                jnp.int32(query_point.cell), radius, mesh=mesh,
+                nb_layers=nb_layers, n=self.grid.n, k=k,
+                strategy=self._knn_strategy())
+
+        return self._stream_dispatch(batch, local, on_mesh)
 
     def run_bulk(self, parsed, query_point: Point, radius: float,
                  k: Optional[int] = None, *, pad: Optional[int] = None

@@ -22,19 +22,21 @@ dry-run-compiled by ``__graft_entry__.dryrun_multichip``.
 
 Device-truth coverage contract: this module deliberately has NO raw
 ``jax.jit`` sites (enforced by the ``TestJitCoverage`` AST meta-test in
-tier-1). The per-shard bodies are closures over the module-level
-``instrumented_jit`` kernels imported from ``spatialflink_tpu.ops`` —
-their registry hooks live inside the traced bodies, so a fresh shard_map
-trace that misses the inner jaxpr cache feeds the compile registry
-(``utils.deviceplane``) exactly like a single-device compile, and the
-recompile sentinel sees multichip recompiles through the same inner
-entries. Wrapping the per-call ``shard_map`` closures themselves in
-``instrumented_jit`` would register a fresh entry per invocation
-(closure identity churn) and corrupt the per-function compile counters —
-don't.
+tier-1). The point kNN (the served kNN operator, trajectory kNN and
+:func:`distributed_knn`) is one module-level ``instrumented_jit``
+program, :func:`knn_mesh_stats`: the mesh and the kernel's shape
+parameters are static, the query point and radius are arguments, so a
+window of a seen batch bucket dispatches the compiled program and lowers
+nothing. Its per-shard body calls the single-device ``knn_point_stats``
+(jit-in-jit), whose registry hook feeds the compile registry
+(``utils.deviceplane``) like a single-device compile.
+The other ops here build their ``shard_map`` per call around closures over
+the same module-level kernels; they trace per call.
 """
 
 from __future__ import annotations
+
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -42,9 +44,11 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from spatialflink_tpu.models.batches import PointBatch
 from spatialflink_tpu.ops.join import join_mask
-from spatialflink_tpu.ops.knn import KnnResult, knn_point, topk_by_distance
+from spatialflink_tpu.ops.knn import (KnnResult, knn_point, knn_point_stats,
+                                      topk_by_distance)
 from spatialflink_tpu.ops.range import range_filter_point
 from spatialflink_tpu.parallel.mesh import CELL_AXIS, DCN_AXIS
+from spatialflink_tpu.utils.deviceplane import instrumented_jit
 
 
 def distributed_knn(
@@ -54,29 +58,22 @@ def distributed_knn(
     qy,
     q_cell,
     radius,
-    nb_layers,
+    nb_layers: int,
     *,
     n: int,
     k: int,
     enforce_radius: bool = False,
     strategy: str = "auto",
 ) -> KnnResult:
-    """kNN over a batch sharded on the point dim; result replicated.
-
-    ``strategy`` is threaded to the per-shard ``knn_point_stats`` so
-    approximate mode (``approx``) behaves the same at any parallelism; the
-    re-merge is exact top-k over the k-sized partials either way. Thin facade
-    over :func:`distributed_stream_knn` (one implementation of the
-    gather+re-merge for every stream type)."""
-    from spatialflink_tpu.ops.knn import knn_point_stats
-
-    def local(pts: PointBatch):
-        return knn_point_stats(
-            pts, qx, qy, q_cell, radius, nb_layers,
-            n=n, k=k, enforce_radius=enforce_radius, strategy=strategy)
-
-    res, _evals = distributed_stream_knn(
-        mesh, points, k=k, strategy=strategy, local_fn=local)
+    """kNN over a batch sharded on the point dim; result replicated — the
+    KnnResult of :func:`knn_mesh_stats` (one compiled program for every
+    point-kNN mesh caller). ``strategy`` is threaded to the per-shard
+    ``knn_point_stats`` so approximate mode (``approx``) behaves the same at
+    any parallelism; the re-merge is exact top-k over the k-sized partials
+    either way."""
+    res, _evals = knn_mesh_stats(
+        points, qx, qy, q_cell, radius, mesh=mesh, nb_layers=nb_layers,
+        n=n, k=k, enforce_radius=enforce_radius, strategy=strategy)
     return res
 
 
@@ -112,7 +109,7 @@ def distributed_knn_hierarchical(
         )
         # level 1 across the slice (ICI), level 2 per-slice partials across
         # hosts (DCN) — ONE merge implementation (_gather_topk) shared with
-        # distributed_stream_knn's 2-D path
+        # the stream kNN ops' 2-D path
         return _gather_topk(_gather_topk(local, CELL_AXIS, k), DCN_AXIS, k)
 
     fn = jax.shard_map(
@@ -236,29 +233,26 @@ def _stream_filter_impl(mesh: Mesh, batch, stats_fn, mask_spec):
     return fn(batch)
 
 
-def distributed_stream_knn(mesh: Mesh, batch, elig_dist_fn=None, *, k: int,
-                           strategy: str = "auto", local_fn=None):
-    """Geometry/point STREAM kNN over the mesh: per-shard local dedup+top-k,
+def distributed_stream_knn(mesh: Mesh, batch, elig_dist_fn, *, k: int,
+                           strategy: str = "auto"):
+    """Geometry STREAM kNN over the mesh: per-shard local dedup+top-k,
     all-gather of the k-sized partials, re-top-k — the generic-stream twin of
-    :func:`distributed_knn` (kills the reference's parallelism-1 ``windowAll``
+    :func:`knn_mesh_stats` (kills the reference's parallelism-1 ``windowAll``
     for the polygon/linestring pairs too). Returns (KnnResult replicated,
     dist_evals total) with the candidate count psum-merged for the pruning
     counter.
 
     Per-shard compute goes through the SAME module-level jitted kernels the
-    single-device paths use — ``local_fn(shard) -> (KnnResult, count)``
-    (e.g. a ``knn_point_stats`` closure) or ``elig_dist_fn(shard) ->
-    (eligible, dists)`` fed into ``knn_eligible_stats`` — so XLA fuses the
-    distance math identically in both paths and the 8-dev ≡ 1-dev parity is
-    bit-for-bit, not just approximate. The re-merge is value-preserving
-    (top-k selects, never recomputes), so merged distances are exact copies
-    of per-shard results.
+    single-device paths use — ``elig_dist_fn(shard) -> (eligible, dists)``
+    fed into ``knn_eligible_stats`` — so XLA fuses the distance math
+    identically in both paths and the 8-dev ≡ 1-dev parity is bit-for-bit,
+    not just approximate. The re-merge is value-preserving (top-k selects,
+    never recomputes), so merged distances are exact copies of per-shard
+    results.
     """
     from spatialflink_tpu.ops.knn import knn_eligible_stats
 
     def local(b):
-        if local_fn is not None:
-            return local_fn(b)
         eligible, dists = elig_dist_fn(b)
         return knn_eligible_stats(b.obj_id, dists, eligible,
                                   k=k, strategy=strategy)
@@ -275,14 +269,38 @@ def distributed_stream_knn_multi(mesh: Mesh, batch, local_fn, *, k: int):
     return _stream_knn_impl(mesh, batch, local_fn, k, _gather_topk_multi)
 
 
-def _stream_knn_impl(mesh: Mesh, batch, local_fn, k: int, gather):
-    """Shared shard_map wiring for the single- and multi-query stream kNN —
-    they differ only in the partial shape ((k,) vs (Q, k)) and hence the
-    gather-merge helper."""
+@partial(instrumented_jit,
+         static_argnames=("mesh", "nb_layers", "n", "k", "enforce_radius",
+                          "strategy"))
+def knn_mesh_stats(points: PointBatch, qx, qy, q_cell, radius, *,
+                   mesh: Mesh, nb_layers: int, n: int, k: int,
+                   enforce_radius: bool = False, strategy: str = "auto"):
+    """The point kNN over a mesh as ONE compiled program a (mesh, batch
+    bucket, k, radius rule, strategy, layer count): ``knn_point_stats`` on
+    each shard of the point dim, the all-gather + re-top-k merge of the k-sized partials
+    (:func:`_gather_topk`) and the psum of the candidate count. The query
+    point, its cell and the radius are traced arguments, so nothing is
+    baked in per window. Returns (KnnResult replicated, dist_evals total),
+    equal to single-device ``knn_point_stats`` on the whole batch."""
+
+    def local(b, qx, qy, q_cell, radius):
+        return knn_point_stats(b, qx, qy, q_cell, radius, nb_layers, n=n,
+                               k=k, enforce_radius=enforce_radius,
+                               strategy=strategy)
+
+    return _stream_knn_impl(mesh, points, local, k, _gather_topk,
+                            qx, qy, q_cell, radius)
+
+
+def _stream_knn_impl(mesh: Mesh, batch, local_fn, k: int, gather, *rep):
+    """Shared shard_map wiring for the stream kNN ops — they differ only in
+    the partial shape ((k,) vs (Q, k)) and hence the gather-merge helper.
+    ``rep`` are replicated operands passed to ``local_fn`` after the
+    shard."""
     axes = _point_axes(mesh)
 
-    def per_shard(b):
-        local, n_elig = local_fn(b)
+    def per_shard(b, *r):
+        local, n_elig = local_fn(b, *r)
         # level 1: merge k-sized partials across the slice (ICI axis)
         merged = gather(local, CELL_AXIS, k)
         if DCN_AXIS in axes:
@@ -298,10 +316,10 @@ def _stream_knn_impl(mesh: Mesh, batch, local_fn, k: int, gather):
         per_shard,
         mesh=mesh,
         check_vma=False,
-        in_specs=(P(axes),),
+        in_specs=(P(axes),) + (P(),) * len(rep),
         out_specs=(KnnResult(P(), P(), P()), P()),
     )
-    return fn(batch)
+    return fn(batch, *rep)
 
 
 def _gather_topk(partial: KnnResult, axis_name: str, k: int) -> KnnResult:

@@ -54,7 +54,7 @@ import random
 import threading
 import time
 from dataclasses import dataclass, fields, replace
-from typing import List, Optional
+from typing import Callable, List, Optional
 
 
 class TransientBrokerError(Exception):
@@ -142,23 +142,33 @@ class StallFault:
     - each subsequent ``on_window`` sleeps ``emit_delay_s`` — the worker
       is SLOW, not dead: it keeps appending outbox rows after the
       supervisor has presumed it dead, which is exactly the stale-fence
-      traffic the containment tests need to observe being dropped.
+      traffic the containment tests need to observe being dropped. The
+      wait lasts, in ``emit_delay_s`` steps, until ``superseded()`` says a
+      successor's fence has been issued (or the stall ends), so the window
+      is written past the fence whatever the timing.
 
     Installed process-globally (:func:`install_stall`) because the
     checkpoint coordinator has no handle on the worker context."""
 
-    def __init__(self, duration_s: float, *, emit_delay_s: float = 0.1):
+    def __init__(self, duration_s: float, *,
+                 superseded: Callable[[], bool],
+                 emit_delay_s: float = 0.1):
         self.duration_s = float(duration_s)
         self.emit_delay_s = float(emit_delay_s)
         self._armed_at: Optional[float] = None
+        #: has this incarnation been fenced out?
+        self.superseded = superseded
 
     def on_window(self) -> None:
         if self._armed_at is None:
             self._armed_at = time.monotonic()
             from spatialflink_tpu.utils.metrics import REGISTRY
             REGISTRY.counter("chaos-stall").inc()
-        elif self.wedged():
+            return
+        while self.wedged():
             time.sleep(self.emit_delay_s)
+            if self.superseded():
+                return
 
     def wedged(self) -> bool:
         return (self._armed_at is not None

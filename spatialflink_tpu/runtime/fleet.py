@@ -698,6 +698,14 @@ class FleetManifest:
 # worker context (driver glue)
 
 
+def fence_superseded(fleet_dir: str, worker_id: int, fence: int) -> bool:
+    """Has the supervisor issued worker slot ``worker_id`` a newer fence
+    than ``fence`` (read-only, from the manifest)?"""
+    state = read_json(os.path.join(fleet_dir, MANIFEST_FILE)) or {}
+    fences = state.get("fences") or {}
+    return int(fences.get(str(int(worker_id)), 0)) > int(fence)
+
+
 class WorkerContext:
     """Everything ``--fleet-role worker`` adds to a driver run: the
     worker's fleet directory layout, the heartbeat, the canonical outbox,
@@ -732,18 +740,19 @@ class WorkerContext:
         fault layer's injectable gray failure for chaos runs."""
         if getattr(args, "fleet_role", None) != "worker":
             return None
+        fence = int(getattr(args, "fleet_fence", 0) or 0)
         stall = None
         stall_s = float(getattr(args, "fleet_stall_s", 0) or 0)
         if stall_s > 0:
             from spatialflink_tpu.runtime.faults import (StallFault,
                                                          install_stall)
-            stall = install_stall(StallFault(stall_s))
+            stall = install_stall(StallFault(
+                stall_s, superseded=lambda: fence_superseded(
+                    args.fleet_dir, args.fleet_worker_id, fence)))
         return WorkerContext(args.fleet_dir, args.fleet_worker_id,
                              family=spec.family,
                              heartbeat_s=args.fleet_heartbeat,
-                             fence=int(getattr(args, "fleet_fence", 0)
-                                       or 0),
-                             stall=stall)
+                             fence=fence, stall=stall)
 
     @property
     def partition_path(self) -> str:

@@ -18,10 +18,12 @@ dimensions, all host-side and all O(1) per observation:
   decode; ``decode`` off the broker) and ``decode.materialize`` (per-record
   ``Point`` objects for the flatten consumers); then ``<q>.dispatch``
   (host batch build, transfer and async launch — not the kernel, whose
-  time is on the device), ``<q>.merge`` (the blocking readback), the
-  join's ``join.reduce`` / ``join.compact`` / ``join.lattice`` /
-  ``join.pairs`` (pre-pass and count readback, row compaction, each mask
-  tile, the pair tuples), and ``sink`` / ``kafka.sink``. No span stays
+  time is on the device; on a mesh it holds ``<q>.place``, the batch put
+  on the chips with its point dim sharded), ``<q>.merge`` (the blocking
+  readback), the join's ``join.reduce`` / ``join.compact`` /
+  ``join.lattice`` / ``join.pairs`` (pre-pass and count readback, row
+  compaction, each mask tile, the pair tuples), and ``sink`` /
+  ``kafka.sink``. No span stays
   open across a ``yield``; the per-window ones carry ``window=<start>``.
 - :class:`StreamingHistogram` — fixed log-bucket histogram (geometric
   buckets, O(1) record, constant memory) exposing p50/p95/p99/max; the

@@ -118,3 +118,27 @@ def test_knn_approx_verified_fast_path_compiles_for_v5e(one_chip):
                           _spec((N_1M,), jnp.float32, one_chip),
                           _spec((N_1M,), jnp.bool_, one_chip)).compile()
     assert compiled.memory_analysis() is not None
+
+
+def test_knn_mesh_stats_compiles_for_v5e_2x2(topo):
+    """The point kNN's mesh program over the host's four chips: the
+    per-shard kernel, the all-gather of the k-sized partials and the psum
+    of the candidate count, in one program (a small window, for the
+    full-sort fallback's compile time)."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from spatialflink_tpu.parallel.mesh import CELL_AXIS
+    from spatialflink_tpu.parallel.ops import knn_mesh_stats
+
+    mesh = Mesh(np.array(topo.devices[:4]), (CELL_AXIS,))
+    shards, rep = NamedSharding(mesh, P(CELL_AXIS)), NamedSharding(mesh, P())
+    f, i = jnp.float32, jnp.int32
+    compiled = knn_mesh_stats.lower(
+        _batch(N_KNN_FULL, shards), _scalar(f, rep), _scalar(f, rep),
+        _scalar(i, rep), _scalar(f, rep), mesh=mesh, nb_layers=24,
+        n=GRID_N, k=50, strategy="approx_verified").compile()
+    # the TPU compiler writes the all-gather as an all-reduce of
+    # partition-placed slices, beside the psum's
+    assert "all-reduce" in compiled.as_text()
+    assert compiled.memory_analysis() is not None

@@ -420,7 +420,7 @@ def test_emitted_journal_fence_stamping_and_cutoffs(tmp_path):
 def test_stall_fault_arms_wedges_and_expires():
     from spatialflink_tpu.runtime import faults
 
-    f = faults.StallFault(0.2, emit_delay_s=0.0)
+    f = faults.StallFault(0.2, superseded=lambda: True, emit_delay_s=0.0)
     assert not f.wedged()  # unarmed until the first emitted window
     f.on_window()
     assert f.wedged()
@@ -442,7 +442,7 @@ def test_stall_fault_gates_checkpoint_due(tmp_path):
                                   every_batches=1)
     coord.note_batch()
     assert coord.due() is True
-    f = faults.StallFault(30.0)
+    f = faults.StallFault(30.0, superseded=lambda: True)
     f.on_window()  # armed + wedged
     prev = faults.active_stall()
     try:
@@ -453,6 +453,44 @@ def test_stall_fault_gates_checkpoint_due(tmp_path):
     finally:
         faults.install_stall(prev)
     assert coord.due() is True
+
+
+def test_stall_fault_holds_wedged_writes_until_superseded(tmp_path):
+    """A wedged worker's windows after the first wait for its successor's
+    fence (read from the manifest), so the zombie writes past the fence
+    however long the supervisor takes to issue it."""
+    from types import SimpleNamespace
+
+    from spatialflink_tpu.runtime import faults
+
+    polls = []
+    f = faults.StallFault(
+        30.0, emit_delay_s=0.01,
+        superseded=lambda: polls.append(1) or len(polls) >= 3)
+    f.on_window()  # arms: the first window is written at once
+    assert polls == []
+    f.on_window()
+    assert len(polls) == 3
+
+    args = SimpleNamespace(fleet_role="worker", fleet_dir=str(tmp_path),
+                           fleet_worker_id=0, fleet_heartbeat=1.0,
+                           fleet_fence=0, fleet_stall_s=30.0)
+    prev = faults.active_stall()
+    ctx = F.WorkerContext.from_args(args, SimpleNamespace(family="range"))
+    try:
+        assert ctx.stall is faults.active_stall()
+        assert ctx.stall.superseded() is False
+        m = F.FleetManifest(os.path.join(str(tmp_path), F.MANIFEST_FILE))
+        m.bump_fence(1)
+        m.save()
+        assert ctx.stall.superseded() is False  # another slot's fence
+        m.bump_fence(0)
+        m.save()
+        assert ctx.stall.superseded() is True
+        assert F.fence_superseded(str(tmp_path), 0, 1) is False
+    finally:
+        ctx.close()
+        faults.install_stall(prev)
 
 
 def test_parse_rescale_and_stall_chaos():

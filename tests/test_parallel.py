@@ -771,7 +771,7 @@ class TestElasticDegradedMode:
         def always_fail(*a, **kw):
             raise RuntimeError("injected device loss (test)")
 
-        monkeypatch.setattr(pops, "distributed_stream_knn", always_fail)
+        monkeypatch.setattr(pops, "knn_mesh_stats", always_fail)
         op = PointPointKNNQuery(self._conf(8), GRID)
         with pytest.raises(RuntimeError, match="refusing to silently"):
             list(op.run(iter(pts), q, 0.5, 15))

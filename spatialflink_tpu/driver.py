@@ -129,9 +129,10 @@ CASES = _build_cases()
 
 class ChunkedStream:
     """The decoded stream as the operators consume it: iterating yields
-    spatial objects (the legacy record contract — joins, trajectory,
-    realtime and the apps flatten through here), while chunk-aware window
-    drivers (``WindowAssembler.assemble`` / ``PaneBuffer.assemble``) pull
+    spatial objects (the legacy record contract — the realtime and pane
+    joins, trajectory, realtime and the apps flatten through here), while
+    chunk-aware window drivers (``WindowAssembler.assemble`` /
+    ``PaneBuffer.assemble``, and the windowed joins' two assemblers) pull
     :meth:`chunks` and never materialize per-record objects at all.
     ``interner`` is the stream's one obj-id space (kNN resolution and
     pane-merge tie order read through it)."""
@@ -158,7 +159,7 @@ class ChunkedStream:
                 else:
                     recs = ch.records()
                 if ch.note is not None and ch.positions is not None:
-                    # flatten consumers (joins, trajectory state machines)
+                    # flatten consumers (realtime/pane joins, trajectory)
                     # pull one record at a time: re-note checkpoint
                     # positions per record so a barrier can never cover
                     # records still buffered in this loop

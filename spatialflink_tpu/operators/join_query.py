@@ -17,7 +17,9 @@ import heapq
 from collections import deque
 from typing import Dict, Iterable, Iterator, List, Tuple
 
-from spatialflink_tpu.models import Point
+import numpy as np
+
+from spatialflink_tpu.models import Point, PointBatch
 from spatialflink_tpu.operators.base import (
     Deferred,
     QueryType,
@@ -26,6 +28,7 @@ from spatialflink_tpu.operators.base import (
 )
 from spatialflink_tpu.ops.join import join_pairs_host
 from spatialflink_tpu.runtime import WindowAssembler
+from spatialflink_tpu.streams.bulk import LazyRecords
 from spatialflink_tpu.utils import telemetry as _telemetry
 
 
@@ -36,6 +39,56 @@ def _merge_by_time(a: Iterable[Point], b: Iterable[Point]) -> Iterator[Tuple[int
         ((p.timestamp, 1, p) for p in b),
         key=lambda t: t[0],
     )
+
+
+def _chunks(stream) -> Iterator:
+    """A join side as decoded chunks: a chunked decode stream
+    (``driver.decode_stream``) hands its chunks over whole; any other
+    iterable is read one record at a time, as one-record chunks."""
+    chunks_fn = getattr(stream, "chunks", None)
+    if chunks_fn is not None:
+        return iter(chunks_fn())
+    return ([rec] for rec in stream)
+
+
+def _assemble_chunk(wa: WindowAssembler, chunk) -> Iterator[Tuple[int, int, List]]:
+    """Buffer one decoded chunk whole: a columnar :class:`PointChunk` as SoA
+    slices, a record list (geometry or mixed streams) as records. A
+    one-record chunk takes :meth:`WindowAssembler.add`, the same decisions
+    as ``add_chunk`` without its per-call array set-up."""
+    if hasattr(chunk, "parsed"):
+        return wa.add_parsed_chunk(chunk)
+    if len(chunk) == 1:
+        return wa.add(chunk[0].timestamp, chunk[0])
+    return wa.add_chunk([r.timestamp for r in chunk], chunk)
+
+
+def _take(recs, idx) -> List:
+    """The records at ``idx``. A columnar window gathers each distinct
+    record once, in one vectorized :meth:`LazyRecords.take`, and pairs
+    that share a member share its object, as a record list's do."""
+    take = getattr(recs, "take", None)
+    if take is None:
+        return [recs[i] for i in idx.tolist()]
+    uniq, inv = np.unique(idx, return_inverse=True)
+    rows = list(take(uniq))
+    return [rows[i] for i in inv.tolist()]
+
+
+def _gather_pairs(recs_a, recs_b, ai, bi, max_dt: int = None
+                  ) -> List[Tuple[object, object]]:
+    """The ``(a, b)`` record pairs at survivor indices ``ai``/``bi``, in
+    lattice order: padding rows dropped, and with ``max_dt`` (the realtime
+    co-residence bound) only pairs whose event times lie within it."""
+    keep = (ai < len(recs_a)) & (bi < len(recs_b))
+    ai, bi = ai[keep], bi[keep]
+    if not ai.size:
+        return []
+    pairs = zip(_take(recs_a, ai), _take(recs_b, bi))
+    if max_dt is None:
+        return list(pairs)
+    return [(a, b) for a, b in pairs
+            if abs(a.timestamp - b.timestamp) <= max_dt]
 
 
 def _combine_windows(r1: WindowResult, r2: WindowResult) -> WindowResult:
@@ -74,9 +127,9 @@ def _merge_sorted_windows(gen_a, gen_b):
 def _spanned_windows(results: Iterator[WindowResult], tel, label: str
                      ) -> Iterator[WindowResult]:
     """Each pull of the next window timed as the ``<label>.window`` span,
-    closed before the window is handed on: the merge of the two streams,
-    window assembly, and every stage nested in them (decode, dispatch,
-    pair extraction)."""
+    closed before the window is handed on: the pull of both streams'
+    chunks, window assembly, and every stage nested in them (decode,
+    dispatch, pair extraction)."""
     it = iter(results)
     while True:
         try:
@@ -263,6 +316,17 @@ class PointPointJoinQuery(SpatialOperator):
     # ---------------------------------------------------------------- #
 
     def _run_windowed(self, ordinary, query_stream, radius) -> Iterator[WindowResult]:
+        """Each side buffers whole decoded chunks into its own assembler
+        (a columnar :class:`PointChunk` as slices, a record list as
+        records; a plain iterable arrives as one-record chunks), the next
+        chunk always from the side whose watermark is lower. Every pulled
+        chunk is in its assembler before any window yields, so a
+        checkpoint barrier never covers a record outside the snapshot.
+        Late drops read each side's own prefix watermark, so window
+        contents, late drops and emission order match a per-record merge
+        of the two streams; emission moves by at most one decode chunk."""
+        from spatialflink_tpu.utils.metrics import REGISTRY
+
         spec = self.conf.window_spec()
         wa_a = WindowAssembler(spec, self.conf.allowed_lateness_ms)
         wa_b = WindowAssembler(spec, self.conf.allowed_lateness_ms)
@@ -272,6 +336,14 @@ class PointPointJoinQuery(SpatialOperator):
         sealed_a: Dict[int, List[Point]] = {}
         sealed_b: Dict[int, List[Point]] = {}
         self._register_ckpt_join(wa_a, wa_b, sealed_a, sealed_b, panes=False)
+        columnar = REGISTRY.counter("join-columnar-windows")
+
+        def join(start: int) -> WindowResult:
+            recs_a = sealed_a.pop(start, [])
+            if isinstance(recs_a, LazyRecords):
+                columnar.inc()
+            return self._join_window(start, start + spec.size_ms, recs_a,
+                                     sealed_b.pop(start, []), radius)
 
         def sweep() -> Iterator[WindowResult]:
             # Empty windows never appear in an assembler's buffers, so a
@@ -282,14 +354,21 @@ class PointPointJoinQuery(SpatialOperator):
                 end = start + spec.size_ms
                 both = start in sealed_a and start in sealed_b
                 if both or end <= wm:
-                    recs_a = sealed_a.pop(start, [])
-                    recs_b = sealed_b.pop(start, [])
-                    yield self._join_window(start, end, recs_a, recs_b, radius)
+                    yield join(start)
 
-        for ts, side, rec in _merge_by_time(ordinary, query_stream):
-            wa = wa_a if side == 0 else wa_b
-            sealed = sealed_a if side == 0 else sealed_b
-            for start, _end, records in wa.add(ts, rec):
+        live = [(wa_a, sealed_a, _chunks(ordinary)),
+                (wa_b, sealed_b, _chunks(query_stream))]
+        while live:
+            # the side whose watermark is lower (side a on a tie); an
+            # exhausted side leaves the rotation
+            i = min(range(len(live)),
+                    key=lambda j: live[j][0].watermarker.watermark)
+            wa, sealed, chunks = live[i]
+            ch = next(chunks, None)
+            if ch is None:
+                del live[i]
+                continue
+            for start, _end, records in _assemble_chunk(wa, ch):
                 sealed[start] = records
             yield from sweep()
         for start, _end, records in wa_a.flush():
@@ -297,10 +376,7 @@ class PointPointJoinQuery(SpatialOperator):
         for start, _end, records in wa_b.flush():
             sealed_b[start] = records
         for start in sorted(set(sealed_a) | set(sealed_b)):
-            yield self._join_window(
-                start, start + spec.size_ms,
-                sealed_a.pop(start, []), sealed_b.pop(start, []), radius,
-            )
+            yield join(start)
 
     def _run_windowed_panes(self, ordinary, query_stream, radius
                             ) -> Iterator[WindowResult]:
@@ -467,8 +543,6 @@ class PointPointJoinQuery(SpatialOperator):
         """
         nb_layers = None if self.prune_cells else self.grid.n
         if self.distributed:
-            import numpy as np
-
             from spatialflink_tpu.parallel.ops import distributed_join_mask
 
             if nb_layers is None:
@@ -491,9 +565,26 @@ class PointPointJoinQuery(SpatialOperator):
                                    nb_layers=nb_layers, window=window)
 
     def _batch_a(self, recs, ts_base):
-        return self._point_batch(recs, ts_base)
+        return self._join_batch(recs, ts_base, self.grid)
 
-    _batch_b = _batch_a
+    def _batch_b(self, recs, ts_base):
+        return self._join_batch(recs, ts_base, self.grid2)
+
+    def _join_batch(self, recs, ts_base, decoded_in):
+        """A side's point batch. A columnar window builds it from the
+        window's memoized per-record arrays, which pair extraction then
+        gathers from (``LazyRecords.take``) without concatenating the
+        window again. Its cells are the decode's, assigned in
+        ``decoded_in``; the join predicate compares cells in
+        ``self.grid``, so they are assigned anew from x/y where the two
+        differ, as a record list's always are (``from_points``)."""
+        if not isinstance(recs, LazyRecords):
+            return self._point_batch(recs, ts_base)
+        x, y, ts, oid, cell = self._record_arrays(recs)
+        if decoded_in is not self.grid:
+            cell = None
+        return PointBatch.from_arrays(x, y, grid=self.grid, obj_id=oid,
+                                      ts=ts, ts_base=ts_base, cell=cell)
 
     def _join_block(self, batch_a, recs_a: List[Point], batch_b,
                     recs_b: List[Point], radius) -> List[Tuple[Point, Point]]:
@@ -505,38 +596,24 @@ class PointPointJoinQuery(SpatialOperator):
         read positions and cells, never the batch ts offsets)."""
         pairs: List[Tuple[Point, Point]] = []
         for ai, bi in self._join_pairs(batch_a, batch_b, radius):
-            pairs.extend(
-                (recs_a[i], recs_b[j])
-                for i, j in zip(ai.tolist(), bi.tolist())
-                if i < len(recs_a) and j < len(recs_b)
-            )
+            pairs.extend(_gather_pairs(recs_a, recs_b, ai, bi))
         return pairs
 
     def _join_window(self, start, end, recs_a: List[Point], recs_b: List[Point],
-                     radius, *, old_a: int = 0, old_b: int = 0,
-                     max_dt: int = None) -> WindowResult:
-        # old_a/old_b: realtime rolling-buffer prefix lengths — pairs with
-        # BOTH members in the prefix were emitted by an earlier fire.
+                     radius, *, max_dt: int = None) -> WindowResult:
         # max_dt: realtime co-residence bound — only pairs whose event times
         # lie within one realtime window of each other are emitted
         pairs: List[Tuple[Point, Point]] = []
         if recs_a and recs_b:
             label = self.telemetry_label
             with _telemetry.span("dispatch", label, window=start):
-                batch_a = self._point_batch(recs_a, start)
-                batch_b = self._point_batch(recs_b, start)
+                batch_a = self._batch_a(recs_a, start)
+                batch_b = self._batch_b(recs_b, start)
             for ai, bi in self._join_pairs(batch_a, batch_b, radius,
                                            window=start):
                 with _telemetry.span("pairs", label, window=start):
-                    pairs.extend(
-                        (recs_a[i], recs_b[j])
-                        for i, j in zip(ai.tolist(), bi.tolist())
-                        if i < len(recs_a) and j < len(recs_b)
-                        and not (i < old_a and j < old_b)
-                        and (max_dt is None
-                             or abs(recs_a[i].timestamp
-                                    - recs_b[j].timestamp) <= max_dt)
-                    )
+                    pairs.extend(_gather_pairs(recs_a, recs_b, ai, bi,
+                                               max_dt))
         return WindowResult(start, end, pairs)
 
 
@@ -545,10 +622,7 @@ class _GenericStreamJoin(PointPointJoinQuery):
     batch construction and the pair-lattice kernel."""
 
     def _join_window(self, start, end, recs_a, recs_b, radius, *,
-                     old_a: int = 0, old_b: int = 0,
                      max_dt: int = None) -> WindowResult:
-        import numpy as np
-
         if not (recs_a and recs_b):
             return WindowResult(start, end, [])
         batch_a = self._batch_a(recs_a, start)
@@ -571,14 +645,7 @@ class _GenericStreamJoin(PointPointJoinQuery):
 
         def collect(m):
             ai, bi = np.nonzero(np.asarray(m))
-            return [
-                (recs_a[i], recs_b[j])
-                for i, j in zip(ai.tolist(), bi.tolist())
-                if i < len(recs_a) and j < len(recs_b)
-                and not (i < old_a and j < old_b)
-                and (max_dt is None
-                     or abs(recs_a[i].timestamp - recs_b[j].timestamp) <= max_dt)
-            ]
+            return _gather_pairs(recs_a, recs_b, ai, bi, max_dt)
 
         return WindowResult(start, end, Deferred(m_dev, collect))
 
@@ -591,8 +658,6 @@ class _GenericStreamJoin(PointPointJoinQuery):
         (single-device or broadcast-sharded) over pre-built pane batches,
         with the pair extraction DEFERRED — blocks stay in flight on device
         until the first covering window's readback."""
-        import numpy as np
-
         if self.distributed:
             from spatialflink_tpu.parallel.ops import (
                 distributed_stream_join_lattice,
@@ -609,11 +674,7 @@ class _GenericStreamJoin(PointPointJoinQuery):
 
         def collect(m):
             ai, bi = np.nonzero(np.asarray(m))
-            return [
-                (recs_a[i], recs_b[j])
-                for i, j in zip(ai.tolist(), bi.tolist())
-                if i < len(recs_a) and j < len(recs_b)
-            ]
+            return _gather_pairs(recs_a, recs_b, ai, bi)
 
         return Deferred(m_dev, collect)
 
@@ -621,9 +682,6 @@ class _GenericStreamJoin(PointPointJoinQuery):
 class PointGeomJoinQuery(_GenericStreamJoin):
     """Point stream x polygon/linestring query stream
     (``join/PointPolygonJoinQuery.java``, ``PointLineStringJoinQuery``)."""
-
-    def _batch_a(self, recs, ts_base):
-        return self._point_batch(recs, ts_base)
 
     def _batch_b(self, recs, ts_base):
         return self._geom_batch(recs, ts_base)
@@ -640,9 +698,6 @@ class GeomPointJoinQuery(_GenericStreamJoin):
 
     def _batch_a(self, recs, ts_base):
         return self._geom_batch(recs, ts_base)
-
-    def _batch_b(self, recs, ts_base):
-        return self._point_batch(recs, ts_base)
 
     def _lattice(self, a, b, radius):
         from spatialflink_tpu.ops.join import join_point_geom_mask

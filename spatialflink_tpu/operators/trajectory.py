@@ -1130,7 +1130,9 @@ class PointPointTJoinQuery(SpatialOperator):
     def run(self, ordinary: Iterable[Point], query_stream: Iterable[Point],
             radius: float) -> Iterator[WindowResult]:
         inner, windowed = self._inner()
-        for res in inner.run(ordinary, query_stream, radius):
+        # flattened: columnar_windows is off for this family, and the inner
+        # join would otherwise take a chunked decode stream's chunks whole
+        for res in inner.run(iter(ordinary), iter(query_stream), radius):
             yield self._post(res, windowed)
 
     def run_single(self, stream: Iterable[Point], radius: float
@@ -1149,7 +1151,7 @@ class PointPointTJoinQuery(SpatialOperator):
         (``tJoin/TJoinQuery.java:61-155``); the exact distance filter still
         applies."""
         inner, windowed = self._inner(prune_cells=False)
-        for res in inner.run(ordinary, query_stream, radius):
+        for res in inner.run(iter(ordinary), iter(query_stream), radius):
             yield self._post(res, windowed)
 
     def _post(self, res: WindowResult, windowed: bool) -> WindowResult:

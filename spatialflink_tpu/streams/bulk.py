@@ -88,7 +88,7 @@ class PointChunk:
     ingest_ms: int = 0
     #: checkpoint-position callback (set by the Kafka commit tap): chunk
     #: consumers that dribble records out one at a time (the flatten path
-    #: feeding joins/trajectory) re-note per record so a checkpoint barrier
+    #: feeding realtime/pane joins and trajectory) re-note per record so a checkpoint barrier
     #: never covers records still sitting in a half-consumed chunk; the
     #: chunk-aware assemblers buffer whole chunks before any barrier can
     #: run, so the tap's chunk-level note is already safe there
@@ -122,7 +122,8 @@ class PointChunk:
 
     def records(self) -> List[Point]:
         """Materialize every record (the flatten path for consumers without
-        a columnar window driver — joins, trajectory, realtime)."""
+        a columnar window driver — realtime/pane joins, trajectory,
+        realtime)."""
         lk = self.parsed.interner.lookup
         ing = self.ingest_ms
         return [
@@ -287,11 +288,13 @@ class PointRows:
 
     def _materialize(self) -> List[Point]:
         if self._mat is None:
-            fx, fy, ft, fo, fc, fi = self._cols
+            # Python scalars in one pass a column: converting numpy
+            # scalars one field at a time costs more than the Point itself
+            fx, fy, ft, fo, fc, fi = (c.tolist() for c in self._cols)
             lk = self.interner.lookup
             self._mat = [
-                Point(obj_id=lk(int(o)), timestamp=int(t), x=float(x),
-                      y=float(y), cell=int(c), ingestion_time=int(g))
+                Point(obj_id=lk(o), timestamp=t, x=x, y=y, cell=c,
+                      ingestion_time=g)
                 for o, t, x, y, c, g in zip(fo, ft, fx, fy, fc, fi)
             ]
         return self._mat

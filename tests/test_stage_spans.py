@@ -1,7 +1,8 @@
 """Stage spans of the served broker path (``--kafka`` over the in-process
 ``memory://`` broker), for the windowed kNN (option 51) and the windowed
 join (option 101): every stage span appears, with one dispatch per window
-and a decode span per chunk; the ingest and decode spans nest inside the
+and a decode span per chunk, and no decoded record is materialized to
+feed window assembly; the ingest and decode spans nest inside the
 window pulls; a ``jax.profiler`` capture holds them as host events on the
 trace's own clock. The telemetry-off contract for these paths is in
 ``tests/test_telemetry.py``."""
@@ -28,7 +29,7 @@ INGEST = ("kafka.fetch", "kafka.poll", "kafka.decode", "decode.materialize")
 EXPECTED = {
     51: {"kafka.fetch", "kafka.poll", "kafka.decode", "knn.window",
          "knn.dispatch", "knn.merge", "sink", "kafka.sink"},
-    101: {"kafka.fetch", "kafka.poll", "kafka.decode", "decode.materialize",
+    101: {"kafka.fetch", "kafka.poll", "kafka.decode",
           "join.window", "join.dispatch", "join.reduce", "join.compact",
           "join.lattice", "join.pairs", "sink", "kafka.sink"},
 }
@@ -113,6 +114,9 @@ def test_stage_names_and_counts(broker_run, recorded, option):
     names = [n for n, *_ in recorded]
     assert EXPECTED[option] <= set(names)
     assert "ingest" not in names
+    # both windowed operators buffer decoded chunks whole: no record is
+    # materialized to feed window assembly
+    assert "decode.materialize" not in names
     q = FAMILY[option]
     # one dispatch per window emitted, and a decode span per chunk
     assert starts and names.count(f"{q}.dispatch") == len(starts)

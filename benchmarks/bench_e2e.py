@@ -7,12 +7,10 @@ time; bench_ingest.py isolates the parsers. This harness measures what the
 reference's Kafka->Flink jobs were actually measured by (throughput meters
 wrapping the live pipeline, ``spatialObjects/Point.java:237-253``): wall
 clock from the first raw record entering deserialization to the last window
-sealed, for the same driver paths a user runs:
+sealed, for the driver path a user runs:
 
-- ``record``: per-record parse -> ``driver.run_option`` (the
-  reference-shaped path; one Python object per tuple)
-- ``bulk``:   native C++ ingest -> ``driver.run_option_bulk`` (columnar
-  windowing; the ``--bulk`` CLI flag)
+- ``record``: raw lines -> ``driver.run_option`` (chunked native decode,
+  columnar windowing, the served path of every mode)
 
 Usage: python benchmarks/bench_e2e.py [--n N] [--options 1,51,101]
        [--out PATH]
@@ -82,96 +80,81 @@ def _drain(it) -> int:
 def bench_option(option: int, path: str, path2, n: int) -> list:
     from spatialflink_tpu import driver
 
-    rows = []
     needs2 = driver.CASES[option].family == "join"
 
-    # bulk first: it warms the jit cache the record path reuses, so the
-    # record row measures steady-state host cost, not compiles
-    p = _params(option)
-    t0 = time.perf_counter()
-    it = driver.run_option_bulk(p, path, path2 if needs2 else None)
-    windows = _drain(it) if it is not None else None
-    dt = time.perf_counter() - t0
-    if windows is not None:
-        rows.append(dict(option=option, path="bulk", records=n,
-                         windows=windows, wall_s=round(dt, 3),
-                         records_per_sec=round(n / dt)))
-    else:
-        # visible, not silent: without the bulk pass the record row below
-        # also pays jit compiles instead of measuring steady-state host cost
-        print(f"warning: option {option}: bulk path declined "
-              "(run_option_bulk returned None); bulk row omitted and the "
-              "record row includes jit-compile time", file=sys.stderr)
+    def run() -> tuple:
+        p = _params(option)
+        with open(path) as f1:
+            streams = [f1]
+            if needs2:
+                streams.append(open(path2))
+            try:
+                t0 = time.perf_counter()
+                windows = _drain(driver.run_option(p, *streams))
+                return windows, time.perf_counter() - t0
+            finally:
+                for s in streams[1:]:
+                    s.close()
 
-    p = _params(option)
-    with open(path) as f1:
-        streams = [f1]
-        if needs2:
-            streams.append(open(path2))
-        try:
-            t0 = time.perf_counter()
-            windows = _drain(driver.run_option(p, *streams))
-            dt = time.perf_counter() - t0
-        finally:
-            for s in streams[1:]:
-                s.close()
-    rows.append(dict(option=option, path="record", records=n,
-                     windows=windows, wall_s=round(dt, 3),
-                     records_per_sec=round(n / dt)))
-    return rows
-
-
-class _BulkDeclined(Exception):
-    pass
+    # an untimed pass first warms the jit cache, so the row measures
+    # steady-state host cost, not compiles
+    run()
+    windows, dt = run()
+    return [dict(option=option, path="record", records=n, windows=windows,
+                 wall_s=round(dt, 3), records_per_sec=round(n / dt))]
 
 
 def _window_table(results, option: int) -> list:
     """Canonical (start, end, sorted-records) table for the pane identity
-    check: bulk range windows carry original-record index lists, kNN
-    windows (objID, distance) pairs."""
+    check: range windows carry points, kNN windows (objID, distance)
+    pairs."""
     table = []
     for r in results:
-        recs = r.records
+        recs = list(r.records)
         if recs and isinstance(recs[0], tuple):
             recs = [(o, round(float(d), 6)) for o, d in recs]
+        else:
+            recs = [(p.obj_id, p.timestamp) for p in recs]
         table.append((r.window_start, r.window_end, sorted(recs)))
     return table
+
+
+def _served_run(p, spec, path: str, q):
+    """One served run of a range/kNN case over the replay file: chunked
+    decode + the operator's windowed pipeline."""
+    from spatialflink_tpu import driver
+
+    u_grid, _ = p.grids()
+    op = driver._operator_class(spec)(driver._query_conf(p, spec), u_grid)
+    with open(path) as f:
+        stream = driver.decode_stream(f, p.input1, u_grid)
+        if spec.family == "range":
+            return _window_table(op.run(stream, q, p.query.radius),
+                                 p.query.option)
+        return _window_table(op.run(stream, q, p.query.radius, p.query.k),
+                             p.query.option)
 
 
 def bench_panes(option: int, path: str, n: int, overlap: int) -> list:
     """Pane-incremental vs full-recompute at sliding overlap ``overlap``
     (window = overlap * slide), same backend, same replay — with window-
     table IDENTITY asserted in the same run (panes are an execution
-    strategy, not a semantics change). The replay is parsed ONCE outside
-    the timed region and both modes drive the operator's bulk windowed
-    pipeline over it: the rows measure window assembly + kernels +
-    readback — the stage panes optimize; ingest is byte-identical in both
-    modes. The on-row carries the measured speedup."""
+    strategy, not a semantics change). Both modes drive the served
+    pipeline over the replay: the rows measure decode + window assembly +
+    kernels + readback, with the decode identical in both modes. The
+    on-row carries the measured speedup."""
     from spatialflink_tpu import driver
 
     p = _params(option)
     p.window.interval_s = SLIDE_S * overlap
     p.window.step_s = SLIDE_S
     spec = driver.CASES[option]
-    parsed = driver._bulk_parse_stream(p.input1, path,
-                                       p.query.allowed_lateness_s)
-    if parsed is None:
-        print(f"warning: option {option}: bulk ingest declined for the "
-              "pane rows; rows omitted", file=sys.stderr)
-        raise _BulkDeclined
-    u_grid, _ = p.grids()
-    q = driver._query_object(p, u_grid, spec.query)
+    q = driver._query_object(p, p.grids()[0], spec.query)
 
     def run(panes: bool):
         p.query.panes = panes
-        conf = driver._query_conf(p, spec)
-        op = driver._operator_class(spec)(conf, u_grid)
         t0 = time.perf_counter()
-        if spec.family == "range":
-            it = op.run_bulk(parsed, q, p.query.radius)
-        else:
-            it = op.run_bulk(parsed, q, p.query.radius, p.query.k)
-        table = _window_table(it, option)
+        table = _served_run(p, spec, path, q)
         return table, time.perf_counter() - t0
 
     run(False)  # warm the jit caches both modes share
@@ -211,26 +194,13 @@ def bench_pane_state(option: int, path: str, n: int, overlap: int) -> list:
     p.window.step_s = SLIDE_S
     p.query.panes = True
     spec = driver.CASES[option]
-    parsed = driver._bulk_parse_stream(p.input1, path,
-                                       p.query.allowed_lateness_s)
-    if parsed is None:
-        print(f"warning: option {option}: bulk ingest declined for the "
-              "pane-state rows; rows omitted", file=sys.stderr)
-        raise _BulkDeclined
-    u_grid, _ = p.grids()
-    q = driver._query_object(p, u_grid, spec.query)
+    q = driver._query_object(p, p.grids()[0], spec.query)
 
     def run(device: bool):
         p.query.pane_device_merge = device
-        conf = driver._query_conf(p, spec)
-        op = driver._operator_class(spec)(conf, u_grid)
         with scoped_registry() as reg:
             t0 = time.perf_counter()
-            if spec.family == "range":
-                it = op.run_bulk(parsed, q, p.query.radius)
-            else:
-                it = op.run_bulk(parsed, q, p.query.radius, p.query.k)
-            table = _window_table(it, option)
+            table = _served_run(p, spec, path, q)
             dt = time.perf_counter() - t0
             snap = reg.snapshot()
         return table, dt, snap
@@ -401,32 +371,28 @@ def bench_live_plane(option: int, path: str, n: int) -> list:
 def bench_multi_vs_jobs(option: int, path: str, n: int, q: int) -> list:
     """ONE multiQuery pipeline vs Q sequential single-query pipelines over
     the same replay — the end-to-end form of the 'Q standing queries cost Q
-    reference jobs re-reading the stream' claim. Bulk path for both sides
-    (the throughput configuration)."""
+    reference jobs re-reading the stream' claim. The served path for both
+    sides."""
     from spatialflink_tpu import driver
 
     hotspots = [(116.0 + 0.9 * i / max(q - 1, 1),
                  40.0 + 0.9 * i / max(q - 1, 1)) for i in range(q)]
 
-    def _drain_bulk(p):
-        it = driver.run_option_bulk(p, path)
-        if it is None:  # eligibility gate declined — degrade visibly,
-            print(f"warning: option {option}: bulk path declined for the "
-                  "multi-vs-jobs rows; rows omitted", file=sys.stderr)
-            raise _BulkDeclined
-        return _drain(it)
+    def _drain_served(p):
+        with open(path) as f:
+            return _drain(driver.run_option(p, f))
 
     def run_multi():
         p = _params(option)
         p.query.multi_query = True
         p.query.query_points = hotspots
-        return _drain_bulk(p)
+        return _drain_served(p)
 
     def run_jobs():
         for hx, hy in hotspots:
             p = _params(option)
             p.query.query_points = [(hx, hy)]
-            _drain_bulk(p)
+            _drain_served(p)
 
     # warm both sides (jit compiles; the sequential side would otherwise
     # free-ride on kernels the single-query rows above already compiled
@@ -884,11 +850,7 @@ def main() -> int:
             for opt in (1, 51):
                 if opt not in [int(x) for x in args.options.split(",")]:
                     continue
-                try:
-                    multi_rows = bench_multi_vs_jobs(opt, path, n, args.multi)
-                except _BulkDeclined:
-                    continue
-                for row in multi_rows:
+                for row in bench_multi_vs_jobs(opt, path, n, args.multi):
                     _stamp(row)
                     print(json.dumps(row), flush=True)
                     rows.append(row)
@@ -913,12 +875,8 @@ def main() -> int:
             for opt in (51,):
                 if opt not in [int(x) for x in args.options.split(",")]:
                     continue
-                try:
-                    ps_rows = bench_pane_state(opt, path, n,
-                                               args.pane_state_overlap)
-                except _BulkDeclined:
-                    continue
-                for row in ps_rows:
+                for row in bench_pane_state(opt, path, n,
+                                            args.pane_state_overlap):
                     _stamp(row)
                     print(json.dumps(row), flush=True)
                     rows.append(row)
@@ -941,11 +899,7 @@ def main() -> int:
             for opt in (1, 51):
                 if opt not in [int(x) for x in args.options.split(",")]:
                     continue
-                try:
-                    pane_rows = bench_panes(opt, path, n, args.pane_overlap)
-                except _BulkDeclined:
-                    continue
-                for row in pane_rows:
+                for row in bench_panes(opt, path, n, args.pane_overlap):
                     _stamp(row)
                     print(json.dumps(row), flush=True)
                     rows.append(row)

@@ -1,5 +1,5 @@
 """Broker-path throughput: sustained records/s for the SAME bounded query
-through the three ``--kafka`` execution paths the driver offers, plus the
+through the two ``--kafka`` decode paths the driver offers, plus the
 file-replay reference point — quantifying what each decode/replay tier buys
 (the reference's pipelines are all Kafka-fed, ``StreamingJob.java:473``):
 
@@ -9,11 +9,9 @@ file-replay reference point — quantifying what each decode/replay tier buys
   buffering latency to one poll cycle)
 - ``chunked``: the default bounded drain — raw records batch through the
   native bulk parser in ``WindowCommitTap`` chunks
-- ``bulk``:    ``--kafka --bulk`` — one lazy topic drain through the
-  native ingest + columnar windowing (``run_option_bulk``)
-- ``file``:    ``--bulk`` file replay of the same records (no broker)
+- ``file``:    file replay of the same records (no broker)
 
-All four produce identical windows (asserted). Usage:
+All three produce identical windows (asserted). Usage:
 
     python benchmarks/bench_kafka.py [--n N] [--out PATH]
 
@@ -117,8 +115,7 @@ def main() -> int:
 
         run("record", [], disable_chunked=True)
         run("chunked", [])
-        run("bulk", ["--bulk"])
-        run("file", ["--bulk"], use_file=True)
+        run("file", [], use_file=True)
 
     base = windows_by_path["record"]
     for name, wins in windows_by_path.items():

@@ -3,8 +3,8 @@ kNN and join throughput at growing stream sizes and sliding overlaps
 (window = overlap * slide), panes on vs off, window-table identity asserted
 per configuration.
 
-- kNN rides the bulk windowed pipeline (parse once per size, outside the
-  timed region — the stage panes optimize is window assembly + kernels).
+- kNN rides the served windowed pipeline (chunked decode + window
+  assembly + kernels; the decode is identical in both modes).
 - join rides the record-path windowed pipeline (pane-pair blocks are a
   record-path feature); its stream sizes default to 1/16 of the kNN sizes
   because the O(Na x Nb) pair lattice, not the pane engine, dominates
@@ -35,7 +35,7 @@ import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from benchmarks.bench_e2e import SLIDE_S, _params, _window_table, _write_stream
+from benchmarks.bench_e2e import SLIDE_S, _params, _served_run, _write_stream
 
 
 def _canon_pairs(results) -> list:
@@ -49,11 +49,8 @@ def sweep_knn(path: str, n: int, overlaps, rows: list, backend: str) -> None:
     from spatialflink_tpu import driver
 
     p = _params(51)
-    parsed = driver._bulk_parse_stream(p.input1, path,
-                                       p.query.allowed_lateness_s)
-    u_grid, _ = p.grids()
     spec = driver.CASES[51]
-    q = driver._query_object(p, u_grid, "Point")
+    q = driver._query_object(p, p.grids()[0], "Point")
 
     for overlap in overlaps:
         p.window.interval_s = SLIDE_S * overlap
@@ -61,11 +58,8 @@ def sweep_knn(path: str, n: int, overlaps, rows: list, backend: str) -> None:
 
         def run(panes: bool):
             p.query.panes = panes
-            conf = driver._query_conf(p, spec)
-            op = driver._operator_class(spec)(conf, u_grid)
             t0 = time.perf_counter()
-            table = _window_table(
-                op.run_bulk(parsed, q, p.query.radius, p.query.k), 51)
+            table = _served_run(p, spec, path, q)
             return table, time.perf_counter() - t0
 
         run(False)  # warm BOTH modes' jit shapes outside the timed rows

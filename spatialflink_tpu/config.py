@@ -90,7 +90,7 @@ class StreamConfig:
 
     def geojson_kwargs(self) -> dict:
         """GeoJSON parser kwargs — the single source shared by the record
-        path (driver.decode_stream) and both bulk ingest paths, so a
+        path (driver.decode_stream) and the columnar chunk decodes, so a
         renamed/added attribute cannot let them diverge."""
         return {"property_obj_id": self.geojson_obj_id_attr,
                 "property_timestamp": self.geojson_timestamp_attr,

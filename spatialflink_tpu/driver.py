@@ -28,8 +28,6 @@ import time
 from dataclasses import dataclass
 from typing import Iterable, Iterator, List, Optional, Tuple
 
-import numpy as np
-
 from spatialflink_tpu import operators as ops
 from spatialflink_tpu.config import Params, StreamConfig
 from spatialflink_tpu.index import UniformGrid
@@ -820,195 +818,6 @@ def _run_synthetic(params: Params, conf, grid) -> Iterator[WindowResult]:
 # CLI
 
 
-def _read_src(src) -> Optional[bytes]:
-    """Bulk-input source to bytes: a replay file path, a ``bytes`` block, or
-    a zero-arg callable (the LAZY ``--kafka --bulk`` topic drain — called
-    only after the cheap case/format gates passed, so an ineligible
-    invocation never pays a full topic read). A callable returning None
-    means the source cannot ride the bulk path (caller falls back)."""
-    if callable(src):
-        return src()
-    if isinstance(src, bytes):
-        return src
-    with open(src, "rb") as f:
-        return f.read()
-
-
-def _bulk_parse_stream(cfg: StreamConfig, src,
-                       allowed_lateness_s: int):
-    """Native-ingest one POINT stream (see :func:`_read_src` for accepted
-    sources) + vectorized watermark dropping; None when the format/content
-    cannot ride the bulk path (e.g. a geometry feature in a declared point
-    stream — the record path dead-letters it instead)."""
-    import dataclasses
-
-    from spatialflink_tpu.runtime.watermarks import BoundedOutOfOrderness
-    from spatialflink_tpu.streams.bulk import bulk_parse_csv, bulk_parse_geojson
-    from spatialflink_tpu.utils.telemetry import span as _tel_span
-
-    fmt = cfg.format.lower()
-    if fmt not in ("csv", "tsv", "geojson"):
-        return None
-    data = _read_src(src)
-    if data is None:
-        return None
-    try:
-        # one span covers the whole native parse (the bulk path's "ingest"
-        # stage — a single call, so the module-level nullcontext-when-off
-        # helper is fine here)
-        with _tel_span("ingest", query="bulk"):
-            if fmt in ("csv", "tsv"):
-                delim = "\t" if fmt == "tsv" else cfg.delimiter
-                parsed = bulk_parse_csv(
-                    data, delimiter=delim, schema=_schema4(cfg),
-                    date_format=cfg.date_format)
-            else:
-                parsed = bulk_parse_geojson(data, **cfg.geojson_kwargs())
-    except ValueError as e:
-        print(f"# --bulk: point stream not bulk-ingestible ({e}); "
-              "using the record path", file=sys.stderr)
-        return None
-    # reproduce the record path's watermark dropping (same keep/late rule,
-    # computed in one vectorized pass over the timestamp array)
-    keep = BoundedOutOfOrderness.bulk_keep_mask(
-        parsed.ts, allowed_lateness_s * 1000)
-    if not keep.all():
-        parsed = dataclasses.replace(
-            parsed, x=parsed.x[keep], y=parsed.y[keep], ts=parsed.ts[keep],
-            obj_id=parsed.obj_id[keep])
-    return parsed
-
-
-def run_option_bulk(params: Params, input_path: str,
-                    input_path2: Optional[str] = None) -> Optional[Iterator]:
-    """Vectorized replay fast path for windowed Point/Point range, kNN and
-    join cases over CSV/TSV/GeoJSON point files: native ingest -> bulk window
-    batches -> pipelined kernels, no per-record Python objects. Lateness
-    semantics match the record path exactly. Returns None when the
-    case/format cannot ride it (caller falls back to the record path)."""
-    spec = CASES.get(params.query.option)
-    if spec is None or spec.mode != "window" or spec.latency:
-        return None
-    if params.window.type == "COUNT":
-        # count windows trigger on arrival ORDER; the bulk assemblers build
-        # event-time windows — the record path implements the mode
-        return None
-    if params.query.multi_query:
-        # every range/kNN pair has a bulk multi-query evaluator (point
-        # streams over CSV/TSV/GeoJSON, geometry streams over WKT/GeoJSON);
-        # anything else falls back to the record path (run_option), which
-        # dispatches or errors per the multiQuery eligibility rules —
-        # silently answering only the first configured query would be
-        # worse than the slower path
-        if spec.family not in ("range", "knn"):
-            return None
-        u_grid, _ = params.grids()
-        getter, qname = {
-            "Point": (params.query_point_objects, "queryPoints"),
-            "Polygon": (params.query_polygon_objects, "queryPolygons"),
-            "LineString": (params.query_linestring_objects,
-                           "queryLineStrings"),
-        }[spec.query]
-        qs = getter(u_grid)
-        if not qs:
-            # validate BEFORE the full-file native ingest, like the record
-            # path's _non_empty guard
-            raise ValueError(f"query.{qname} is empty")
-        if spec.stream in ("Polygon", "LineString"):
-            if params.input1.format.lower() not in ("wkt", "geojson"):
-                return None
-            parsed = _bulk_parse_geom_stream(params, input_path)
-        else:
-            parsed = _bulk_parse_stream(params.input1, input_path,
-                                        params.query.allowed_lateness_s)
-        if parsed is None:
-            return None
-        conf = _query_conf(params, spec)
-        cls = _operator_class(spec)
-        if spec.family == "range":
-            return cls(conf, u_grid).run_multi_bulk(
-                parsed, qs, params.query.radius)
-        return cls(conf, u_grid).run_multi_bulk(
-            parsed, qs, params.query.radius, params.query.k)
-    geom_stream = spec.stream in ("Polygon", "LineString")
-    if geom_stream:
-        # geometry STREAMS ride the bulk path for range/kNN over WKT or
-        # GeoJSON files
-        if (spec.family not in ("range", "knn")
-                or params.input1.format.lower() not in ("wkt", "geojson")):
-            return None
-        parsed = _bulk_parse_geom_stream(params, input_path)
-    else:
-        if (spec.family not in ("range", "knn", "join")
-                or spec.stream != "Point"):
-            return None
-        if spec.family == "join":
-            if spec.query != "Point":
-                return None
-            # cheap format gate on BOTH sides before any ingest work, so an
-            # ineligible side-2 format doesn't waste a full side-1 parse
-            if (input_path2 is None or params.input2.format.lower()
-                    not in ("csv", "tsv", "geojson")):
-                return None
-        parsed = _bulk_parse_stream(params.input1, input_path,
-                                    params.query.allowed_lateness_s)
-    if parsed is None:
-        return None
-    u_grid, _ = params.grids()
-    conf = _query_conf(params, spec)
-    if spec.family == "join":
-        parsed2 = _bulk_parse_stream(params.input2, input_path2,
-                                     params.query.allowed_lateness_s)
-        if parsed2 is None:
-            return None
-        return ops.PointPointJoinQuery(conf, u_grid, u_grid).run_bulk(
-            parsed, parsed2, params.query.radius)
-    q = _query_object(params, u_grid, spec.query)
-    cls = _operator_class(spec)
-    if spec.family == "range":
-        return cls(conf, u_grid).run_bulk(parsed, q, params.query.radius)
-    return cls(conf, u_grid).run_bulk(
-        parsed, q, params.query.radius, params.query.k)
-
-
-def _bulk_parse_geom_stream(params: Params, src):
-    """Native WKT/GeoJSON geometry ingest (file path or pre-drained bytes)
-    + the same vectorized watermark dropping as the point path (ParsedGeoms
-    carries its own subset machinery). Returns None — honoring
-    run_option_bulk's fall-back-to-record-path contract — when the input
-    holds geometry the bulk path can't ride (e.g. a stray POINT or
-    GEOMETRYCOLLECTION row in a polygon stream)."""
-    from spatialflink_tpu.runtime.watermarks import BoundedOutOfOrderness
-    from spatialflink_tpu.streams.bulk import (bulk_parse_geojson_geoms,
-                                               bulk_parse_wkt)
-    from spatialflink_tpu.utils.telemetry import span as _tel_span
-
-    cfg = params.input1
-    if cfg.format.lower() == "wkt":
-        kw = dict(delimiter=cfg.delimiter, date_format=cfg.date_format)
-    else:
-        kw = cfg.geojson_kwargs()
-    try:
-        data = _read_src(src)
-        if data is None:
-            return None
-        with _tel_span("ingest", query="bulk"):
-            # format pre-gated to WKT/GeoJSON by run_option_bulk
-            if cfg.format.lower() == "wkt":
-                parsed = bulk_parse_wkt(data, **kw)
-            else:
-                parsed = bulk_parse_geojson_geoms(data, **kw)
-    except ValueError as e:
-        print(f"# --bulk: geometry file not bulk-ingestible ({e}); "
-              "using the record path", file=sys.stderr)
-        return None
-    keep = BoundedOutOfOrderness.bulk_keep_mask(
-        parsed.ts, params.query.allowed_lateness_s * 1000)
-    if not keep.all():
-        parsed = parsed.subset(np.nonzero(keep)[0])
-    return parsed
-
-
 def _emit(result, sink) -> None:
     if isinstance(result, WindowResult):
         if "queries" in result.extras:
@@ -1099,13 +908,13 @@ def _governed_chunk(dchunk: int, pinned: bool = False):
 
 def _schema4(cfg: StreamConfig) -> list:
     """csvTsvSchemaAttr padded to the 4 [oID, ts, x, y] slots (None =
-    absent) — shared by the bulk file path and the kafka chunked decode."""
+    absent) — shared by :func:`decode_chunks` and the kafka chunked decode."""
     return (list(cfg.csv_tsv_schema) + [None] * 4)[:4]
 
 
 def _kafka_bulk_decode(cfg: StreamConfig, grid: UniformGrid):
     """Chunked native decode for broker-fed POINT streams (CSV/TSV/GeoJSON):
-    the bulk replay parser applied to poll batches, returning a COLUMNAR
+    the columnar point parser applied to poll batches, returning a COLUMNAR
     :class:`~spatialflink_tpu.streams.bulk.PointChunk` (vectorized cell
     assignment; per-record Point objects materialize only if a non-columnar
     consumer flattens). None when the format cannot ride it (the tap then
@@ -1193,10 +1002,6 @@ class _KafkaWiring:
     #: micro-batches behind the read head is in a long-emitted batch, so a
     #: restart reprocesses a bounded tail instead of the whole topic
     commit_lag: Optional[int] = None
-    #: set by the --kafka --bulk drain: (topic, next_offset) pairs covering
-    #: the drained range; finish() commits exactly these (the sources were
-    #: never iterated, so their positions are meaningless)
-    bulk_offsets: Optional[List] = None
     #: degradation counters at wiring time: the summary reports the DELTA,
     #: so a later in-process run doesn't inherit an earlier run's chaos/
     #: retry/dlq counts (the registry is process-global)
@@ -1238,10 +1043,6 @@ class _KafkaWiring:
         reflected in produced output, so the full positions commit. NOT
         called on a control-tuple stop or crash — the conservative
         window-aligned commits stand, and restart re-delivers."""
-        if self.bulk_offsets is not None:
-            for topic, off in self.bulk_offsets:
-                self.broker.commit(topic, self.group, off)
-            return
         tapped = {id(t.source) for t in self.taps}
         for tap in self.taps:
             tap.commit_all()
@@ -1270,52 +1071,6 @@ class _KafkaWiring:
             parts.append("degraded: " + ", ".join(
                 f"{k}={v}" for k, v in sorted(deg.items())))
         return "# kafka: " + "; ".join(parts)
-
-
-def _topic_reader(kafka: _KafkaWiring, topic: str, limit: Optional[int],
-                  offsets_out: List):
-    """Zero-arg LAZY drain of one topic for run_option_bulk (called only
-    after the cheap bulk gates pass): committed offset -> current end
-    (bounded by --limit) as newline-joined bytes, recording the drained
-    range in ``offsets_out`` for the post-run commit. Returns None — the
-    fall-back-to-streaming signal — when any record cannot ride the bulk
-    path: non-string values, embedded newlines (they would shift the
-    line<->record mapping), or a control tuple (the streaming path honors
-    its stop semantics)."""
-    def drain() -> Optional[bytes]:
-        b = kafka.broker
-        off = b.committed(topic, kafka.group)
-        end = b.end_offset(topic)
-        if limit is not None:
-            end = min(end, off + limit)
-        from spatialflink_tpu.streams.kafka import resequence_batch
-
-        vals: List[str] = []
-        while off < end:
-            batch = b.fetch(topic, off, min(65536, end - off))
-            if not batch:
-                break
-            for r in resequence_batch(batch, off):
-                v = r.value
-                if not isinstance(v, str) or "\n" in v or '"control"' in v:
-                    print(f"# --kafka --bulk: topic '{topic}' not "
-                          "bulk-drainable (non-string/multiline/control "
-                          "records); using the streaming path",
-                          file=sys.stderr)
-                    return None
-                vals.append(v)
-                off = r.offset + 1
-        offsets_out.append((topic, off))
-        return "\n".join(vals).encode()
-
-    def read() -> Optional[bytes]:
-        from spatialflink_tpu.utils.telemetry import span as _tel_span
-
-        # the drain is the --kafka --bulk path's ingest stage (one call)
-        with _tel_span("ingest", query="kafka-drain"):
-            return drain()
-
-    return read
 
 
 def _wire_kafka(params: Params, spec: CaseSpec, args, skip1: int
@@ -1536,7 +1291,7 @@ def main(argv: Optional[List[str]] = None) -> int:
                          "results on the plain sink stay at-least-once "
                          "across a resume. Windowed + realtime range/kNN, "
                          "windowed join/trajectory, realtime tStats/"
-                         "tAggregate; record path only (not --bulk)")
+                         "tAggregate")
     ap.add_argument("--resume", action="store_true",
                     help="restore the newest valid checkpoint from "
                          "--checkpoint-dir before running (refuses a "
@@ -1662,13 +1417,6 @@ def main(argv: Optional[List[str]] = None) -> int:
                          "dispatch/readback annotations — the reference's "
                          "Flink web UI observability as a trace "
                          "(StreamingJob.java:70-72)")
-    ap.add_argument("--bulk", action="store_true",
-                    help="DEPRECATED alias: the chunk-vectorized decode + "
-                         "bulk window assignment is now the only execution "
-                         "path (every mode), so the flag no longer selects "
-                         "a faster engine — it keeps only its whole-replay "
-                         "semantics (no watermark-paced emission, no "
-                         "control-tuple stop hook) for bounded files/topics")
     ap.add_argument("--pane-merge", choices=["auto", "device", "host"],
                     default=None,
                     help="where --panes partials live and merge: 'device' "
@@ -1953,10 +1701,6 @@ def main(argv: Optional[List[str]] = None) -> int:
         params.query.panes = True
     if args.pane_merge is not None and args.pane_merge != "auto":
         params.query.pane_device_merge = args.pane_merge == "device"
-    if args.bulk:
-        print("note: --bulk is deprecated — the batched columnar path is "
-              "now the default for every mode; the flag keeps only its "
-              "whole-replay semantics (see README)", file=sys.stderr)
     if args.devices is not None:
         params.query.parallelism = args.devices
     if args.hosts is not None:
@@ -2025,9 +1769,8 @@ def main(argv: Optional[List[str]] = None) -> int:
             ap.error("--fleet supports windowed range/kNN cases (the "
                      "windowAll merge families); option "
                      f"{params.query.option} is {spec.family}/{spec.mode}")
-        if args.bulk or params.query.multi_query:
-            ap.error("--fleet does not compose with --bulk or "
-                     "--multi-query")
+        if params.query.multi_query:
+            ap.error("--fleet does not compose with --multi-query")
         if args.queries_file or args.control_topic:
             ap.error("--fleet does not compose with the dynamic query "
                      "plane (each worker runs the static configured "
@@ -2044,9 +1787,6 @@ def main(argv: Optional[List[str]] = None) -> int:
         ap.error("--fleet-role worker needs --fleet-dir, --input1 and "
                  "--checkpoint-dir (workers are spawned by the "
                  "supervisor, not launched directly)")
-    if args.kafka and args.bulk and args.kafka_follow:
-        ap.error("--kafka-follow and --bulk are mutually exclusive "
-                 "(bulk is a bounded vectorized drain, not a live stream)")
     # the dynamic standing-query plane (validated/constructed below, after
     # the checkpointer exists); the flag participates in the checkpoint
     # LAYOUT tag — a dynamic run's manifest carries a 'queries' component a
@@ -2059,10 +1799,6 @@ def main(argv: Optional[List[str]] = None) -> int:
             ap.error("--checkpoint-dir and --checkpoint are mutually "
                      "exclusive (the directory coordinator subsumes the "
                      "single-file tStats/tAggregate checkpoint)")
-        if args.bulk:
-            ap.error("--checkpoint-dir does not compose with --bulk "
-                     "(bulk is a whole-replay; coordinated checkpoints "
-                     "apply to the record path)")
         reason = _checkpoint_dir_unsupported(params, spec)
         if reason:
             print(f"--checkpoint-dir ignored: {reason}", file=sys.stderr)
@@ -2115,38 +1851,31 @@ def main(argv: Optional[List[str]] = None) -> int:
     if args.shard_order != "arrival":
         params.shard_order = args.shard_order
     if args.adaptive_grid is not None:
-        if args.bulk:
-            # the whole-replay alias builds its batches straight from the
-            # parsed file before any window-time refinement could gate them
-            print("--adaptive-grid ignored with --bulk (whole-replay "
-                  "batches bypass the window-time prefilter); the default "
-                  "batched path supports it", file=sys.stderr)
-        else:
-            from spatialflink_tpu.index import AdaptiveGrid
-            from spatialflink_tpu.runtime.repartition import (
-                RepartitionController)
+        from spatialflink_tpu.index import AdaptiveGrid
+        from spatialflink_tpu.runtime.repartition import (
+            RepartitionController)
 
-            try:
-                agrid = AdaptiveGrid(params.grids()[0],
-                                     refine=args.adaptive_grid)
-            except ValueError as e:
-                ap.error(f"--adaptive-grid: {e}")
-            ctl = RepartitionController(
-                agrid, interval_records=args.repartition_interval)
-            coord = getattr(params, "checkpointer", None)
-            if coord is not None:
-                # grid layout rides the coordinated manifest: --resume
-                # restores the adapted partitioning (auto-applied here if
-                # the coordinator already loaded one)
-                ctl.register_checkpoint(coord)
-            # dynamic attributes (not dataclass fields), like checkpointer:
-            # must not leak into Params.to_dict()/fingerprints
-            params.adaptive_grid = agrid
-            params.repartitioner = ctl
-            print(f"# adaptive grid: hot cells split "
-                  f"{args.adaptive_grid}x{args.adaptive_grid}, repartition "
-                  f"epoch every {args.repartition_interval} records "
-                  "(layout at /partition)", file=sys.stderr)
+        try:
+            agrid = AdaptiveGrid(params.grids()[0],
+                                 refine=args.adaptive_grid)
+        except ValueError as e:
+            ap.error(f"--adaptive-grid: {e}")
+        ctl = RepartitionController(
+            agrid, interval_records=args.repartition_interval)
+        coord = getattr(params, "checkpointer", None)
+        if coord is not None:
+            # grid layout rides the coordinated manifest: --resume
+            # restores the adapted partitioning (auto-applied here if
+            # the coordinator already loaded one)
+            ctl.register_checkpoint(coord)
+        # dynamic attributes (not dataclass fields), like checkpointer:
+        # must not leak into Params.to_dict()/fingerprints
+        params.adaptive_grid = agrid
+        params.repartitioner = ctl
+        print(f"# adaptive grid: hot cells split "
+              f"{args.adaptive_grid}x{args.adaptive_grid}, repartition "
+              f"epoch every {args.repartition_interval} records "
+              "(layout at /partition)", file=sys.stderr)
     # tenant quotas parse up front: a malformed SPEC is a flag error, not a
     # mid-run surprise at first admission
     tenant_quotas = {}
@@ -2181,10 +1910,6 @@ def main(argv: Optional[List[str]] = None) -> int:
             ap.error("the dynamic standing-query plane does not combine "
                      "with the latency variants (per-record latency "
                      "assumes single-query record lists)")
-        if args.bulk:
-            ap.error("the dynamic standing-query plane does not compose "
-                     "with --bulk (a whole-replay has no admission "
-                     "boundaries)")
         if params.query.multi_query:
             ap.error("--multi-query is subsumed by the query registry "
                      "(the live fleet IS the multi-query set); drop the "
@@ -2403,30 +2128,7 @@ def _run_cli(ap, args, params: Params, spec: CaseSpec, skip1: int,
 
     from spatialflink_tpu.utils.metrics import ControlTupleExit
 
-    results = None
-    if args.bulk and kafka is not None:
-        # vectorized TOPIC replay: the readers drain committed-offset..end
-        # LAZILY (only once run_option_bulk's cheap case/format gates
-        # pass); the drained offsets commit after the full run produced
-        offs: List = []
-        results = run_option_bulk(
-            params,
-            _topic_reader(kafka, params.input1.topic_name, args.limit, offs),
-            _topic_reader(kafka, params.input2.topic_name, args.limit, offs))
-        if results is None:
-            print("# --kafka --bulk not applicable to this case/format/"
-                  "topic content; using the streaming path", file=sys.stderr)
-        else:
-            kafka.bulk_offsets = offs
-    elif args.bulk:
-        results = run_option_bulk(params, args.input1, args.input2)
-        if results is None:
-            print("--bulk not applicable to this case/format; "
-                  "using the record path", file=sys.stderr)
-        elif args.limit is not None:
-            print("--bulk ignores --limit (whole-file replay)", file=sys.stderr)
-    if results is None:
-        results = run_option(params, stream1, stream2)
+    results = run_option(params, stream1, stream2)
 
     sink = StdoutSink()
     out_sink = None

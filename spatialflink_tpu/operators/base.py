@@ -136,11 +136,11 @@ class QueryConfiguration:
         if self.query_type is QueryType.CountBased:
             # count windows trigger on ARRIVAL ORDER (operators/base.py
             # _count_windows); every caller of this method builds
-            # event-time windows (the bulk replay assemblers), which would
-            # silently reinterpret the count values as milliseconds
+            # event-time windows, which would silently reinterpret the
+            # count values as milliseconds
             raise NotImplementedError(
-                "count windows are record-path only; bulk replay builds "
-                "event-time windows — run() implements CountBased")
+                "count windows are record-path only; a WindowSpec is "
+                "event-time — run() implements CountBased")
         return WindowSpec.sliding(self.window_size_ms, self.slide_ms)
 
 
@@ -844,12 +844,8 @@ class SpatialOperator:
                     return PanePartial(pane_partial(payload, p_start))
                 t0 = time.time()
                 part = PanePartial(pane_partial(payload, p_start))
-                # payload is the pane's record list on the record path, an
-                # (idx, batch) pair on the bulk path — count accordingly
-                n = (len(payload[0]) if isinstance(payload, tuple)
-                     else len(payload))
                 book.note(label, ts_base, "pane-seal", t0, time.time(),
-                          pane=int(p_start), records=int(n))
+                          pane=int(p_start), records=len(payload))
                 return part
 
             parts = [
@@ -955,21 +951,6 @@ class SpatialOperator:
         return EdgeGeomBatch.from_objects(records, self.grid, self.interner,
                                           ts_base=ts_base, pad=pad)
 
-    def _bulk_mask_eval(self, mask_stats_fn):
-        """eval_batch for bulk window payloads ((idx, batch)): one shared
-        mask->original-record-index selection for every stream-filter
-        operator's run_bulk (point and geometry alike)."""
-        import numpy as np
-
-        def eval_batch(payload, ts_base):
-            idx, batch = payload
-            mask, gn_c, evals = self._filter_stream(batch, mask_stats_fn)
-            return self._defer_with_stats(
-                mask, (gn_c, evals),
-                lambda m: idx[np.asarray(m)[: len(idx)]].tolist())
-
-        return eval_batch
-
     def _maybe_cell_order(self, batch):
         """``--shard-order cell``: pre-permute the batch so whole grid
         cells co-locate per shard (``parallel.mesh.cell_hash_order`` —
@@ -1053,9 +1034,10 @@ class SpatialOperator:
 
     def _defer_knn(self, res, interner=None, dist_evals=None) -> Deferred:
         """Deferred (objID, distance) list from a device KnnResult; ids
-        resolve through ``interner`` (default: the operator's own — bulk
-        paths pass the parse-time interner). ``dist_evals`` (device scalar)
-        feeds the distance-computation counter — kNN has no GN bypass
+        resolve through ``interner`` (default: the operator's own — a
+        columnar window passes its decode interner). ``dist_evals``
+        (device scalar) feeds the distance-computation counter — kNN has
+        no GN bypass
         (``knn/PointPointKNNQuery.java:152-183`` computes a distance for
         every candidate-cell point)."""
         interner = interner if interner is not None else self.interner
@@ -1081,7 +1063,7 @@ class SpatialOperator:
         """Deferred per-query (objID, distance) lists from a (Q, k)
         KnnResult; ``dist_evals`` (device scalar, summed over the Q
         queries) feeds the distance-computation counter like every other
-        kNN path. Bulk paths pass the parse-time ``interner``."""
+        kNN path. A columnar window passes its decode ``interner``."""
         interner = interner if interner is not None else self.interner
 
         def rows(r):
@@ -1204,51 +1186,6 @@ class SpatialOperator:
         for result in self._multi_results(
                 stream, eval_batch,
                 pane_merge=self._pane_concat_multi(n_queries)):
-            result.extras["queries"] = n_queries
-            yield result
-
-    def _run_multi_filter_bulk(self, batched, n_queries: int,
-                               multi_mask_stats
-                               ) -> Iterator["WindowResult"]:
-        """Bulk twin of :meth:`_run_multi_filter`: ``batched`` yields
-        (start, end, (idx, batch)) window payloads; records become Q
-        per-query ORIGINAL-RECORD-INDEX lists from one (Q, N) mask dispatch
-        per window."""
-        import jax.numpy as jnp
-
-        def eval_batch(payload, ts_base):
-            idx, batch = payload
-            masks, gn_c, evals = self._multi_filter_stream(
-                batch, multi_mask_stats)
-
-            def rows(m):
-                m = np.asarray(m)  # ONE (Q, N) device->host transfer
-                return [idx[m[q][: len(idx)]].tolist()
-                        for q in range(n_queries)]
-
-            return self._defer_with_stats(
-                masks, (jnp.sum(gn_c), jnp.sum(evals)), rows)
-
-        for result in self._drive_batched(batched, eval_batch,
-                                          count=lambda p: len(p[0])):
-            result.extras["queries"] = n_queries
-            yield result
-
-    def _run_multi_knn_bulk(self, batched, n_queries: int, local, k: int,
-                            interner) -> Iterator["WindowResult"]:
-        """Bulk twin of the kNN multi loops: per-window (Q, k) results with
-        ids resolved through the parse-time ``interner``."""
-        import jax.numpy as jnp
-
-        def eval_batch(payload, ts_base):
-            _idx, batch = payload
-            res, evals = self._knn_multi_result(batch, local, k)
-            return self._defer_knn_multi(res, jnp.sum(evals),
-                                         interner=interner)
-
-        for result in self._drive_batched(batched, eval_batch,
-                                          count=lambda p: len(p[0])):
-            result.extras["k"] = k
             result.extras["queries"] = n_queries
             yield result
 
@@ -1403,34 +1340,6 @@ class SpatialOperator:
         """
         return "approx" if self.conf.approximate else "auto"
 
-    def _drive_bulk(self, parsed, eval_batch, *, pad: Optional[int] = None,
-                    pane_merge=None,
-                    pane_device_merge=None) -> Iterator["WindowResult"]:
-        """Bulk-replay driver: vectorized window batches
-        (``streams.bulk.bulk_window_batches``) through the pipelined
-        evaluator. eval_batch((idx, PointBatch), ts_base) as in _drive.
-        With ``pane_merge`` and pane mode active, per-pane batches are built
-        ONCE (``bulk_pane_window_batches``), the same eval_batch runs once
-        per pane, and windows merge cached partials."""
-        from spatialflink_tpu.streams.bulk import (bulk_pane_window_batches,
-                                                   bulk_window_batches)
-
-        if pane_merge is not None and self._panes_active():
-            pane_windows = bulk_pane_window_batches(
-                parsed, self.conf.window_spec(), self.grid, pad=pad)
-            return self._drive_batched(
-                pane_windows,
-                self._pane_eval(eval_batch, pane_merge,
-                                device_merge=pane_device_merge),
-                count=lambda panes: sum(len(p[1][0]) for p in panes))
-        batched = (
-            (start, end, (idx, batch))
-            for start, end, idx, batch in bulk_window_batches(
-                parsed, self.conf.window_spec(), self.grid, pad=pad)
-        )
-        return self._drive_batched(batched, eval_batch,
-                                   count=lambda p: len(p[0]))
-
     def _drive(self, stream: Iterable, eval_batch, *, pane_merge=None,
                pane_device_merge=None) -> Iterator["WindowResult"]:
         """Shared window/realtime driver.
@@ -1482,9 +1391,9 @@ class SpatialOperator:
     def _drive_batched(self, batched: Iterable, eval_batch, *,
                        realtime: bool = False, count=len
                        ) -> Iterator["WindowResult"]:
-        """Pipelined evaluation over pre-assembled (start, end, payload)
-        triples (record lists from _drive, or index/batch payloads from the
-        bulk path). ``count(payload)`` feeds the records-evaluated metric."""
+        """Pipelined evaluation over the (start, end, payload) triples
+        _drive assembles (record lists, or pane lists in pane mode).
+        ``count(payload)`` feeds the records-evaluated metric."""
         from spatialflink_tpu.utils import telemetry as _telemetry
         from spatialflink_tpu.utils.metrics import REGISTRY, trace
 
@@ -1671,8 +1580,7 @@ class SpatialOperator:
     def _first_ingest_ms(payload):
         """Best-effort first-record ingest wall clock for trace lineage:
         record lists carry Points with an ``ingestion_time`` stamped at
-        parse; pane payloads hold ``(pane_start, records)`` pairs; bulk
-        (idx, batch) payloads have no per-record host objects — None."""
+        parse; pane payloads hold ``(pane_start, records)`` pairs."""
         return SpatialOperator._ingest_ms(payload, -1)
 
     @staticmethod
@@ -1713,37 +1621,21 @@ class SpatialOperator:
 
     @staticmethod
     def _payload_nbytes(payload) -> int:
-        """Approximate host->device bytes for one window payload: summed
-        array ``nbytes`` where the payload carries arrays (bulk
-        (idx, batch) tuples), a flat 32-bytes-per-record estimate for host
-        record lists (x/y/ts/id as packed fields) — a cost-profile
-        ESTIMATE of data motion, not a transfer measurement."""
+        """Approximate host->device bytes for one window payload: a flat
+        32-bytes-per-record estimate (x/y/ts/id as packed fields) — a
+        cost-profile ESTIMATE of data motion, not a transfer
+        measurement."""
         from spatialflink_tpu.streams.bulk import LazyRecords
 
         try:
             if isinstance(payload, LazyRecords):
                 return 32 * len(payload)
-            if isinstance(payload, tuple) and len(payload) == 2:
-                idx, batch = payload
-                total = int(getattr(idx, "nbytes", 0))
-                parts = (batch if isinstance(batch, tuple)
-                         else [getattr(batch, f, None)
-                               for f in getattr(batch,
-                                                "__dataclass_fields__", ())])
-                for a in parts:
-                    total += int(getattr(a, "nbytes", 0) or 0)
-                return total
             if isinstance(payload, list):
                 if (payload and isinstance(payload[0], tuple)
-                        and len(payload[0]) == 2):
-                    inner = payload[0][1]
-                    if isinstance(inner, (list, LazyRecords)):
-                        # record-path pane payload
-                        return 32 * sum(len(rs) for _, rs in payload)
-                    if isinstance(inner, tuple):  # bulk pane payload
-                        return sum(
-                            SpatialOperator._payload_nbytes(p)
-                            for _, p in payload)
+                        and len(payload[0]) == 2
+                        and isinstance(payload[0][1], (list, LazyRecords))):
+                    # pane payload
+                    return 32 * sum(len(rs) for _, rs in payload)
                 return 32 * len(payload)
         except Exception:
             pass

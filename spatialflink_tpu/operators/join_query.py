@@ -106,24 +106,6 @@ def _combine_windows(r1: WindowResult, r2: WindowResult) -> WindowResult:
     return WindowResult(r1.window_start, r1.window_end, Deferred(None, collect))
 
 
-def _merge_sorted_windows(gen_a, gen_b):
-    """Outer-merge two window-start-sorted (start, end, idx, batch) streams
-    into (start, end, a_win|None, b_win|None)."""
-    a = next(gen_a, None)
-    b = next(gen_b, None)
-    while a is not None or b is not None:
-        if b is None or (a is not None and a[0] < b[0]):
-            yield a[0], a[1], (a[2], a[3]), None
-            a = next(gen_a, None)
-        elif a is None or b[0] < a[0]:
-            yield b[0], b[1], None, (b[2], b[3])
-            b = next(gen_b, None)
-        else:
-            yield a[0], a[1], (a[2], a[3]), (b[2], b[3])
-            a = next(gen_a, None)
-            b = next(gen_b, None)
-
-
 def _spanned_windows(results: Iterator[WindowResult], tel, label: str
                      ) -> Iterator[WindowResult]:
     """Each pull of the next window timed as the ``<label>.window`` span,
@@ -502,35 +484,6 @@ class PointPointJoinQuery(SpatialOperator):
         mean_b = sum(bucket_size(max(len(rs), 1))
                      for _, rs in panes_b) / len(panes_b)
         return mean_a * mean_b < min_cells
-
-    def run_bulk(self, parsed_a, parsed_b, radius: float, *,
-                 pad: int = None) -> Iterator[WindowResult]:
-        """Bulk-replay fast path: both sides go through the vectorized window
-        assembler; records are (index_a, index_b) pairs into the two
-        ParsedPoints. Windowed mode only."""
-        from spatialflink_tpu.streams.bulk import bulk_window_batches
-
-        if self.conf.query_type is QueryType.RealTime:
-            raise ValueError("run_bulk supports windowed mode only")
-        spec = self.conf.window_spec()
-        gen_a = bulk_window_batches(parsed_a, spec, self.grid, pad=pad)
-        # both sides must carry cell ids from the SAME grid: join_pairs_host
-        # evaluates the Chebyshev cell predicate in self.grid (as _join_window
-        # does via _point_batch); windowing side b in grid2 would compare cell
-        # ids across different grids and misprune pairs
-        gen_b = bulk_window_batches(parsed_b, spec, self.grid, pad=pad)
-        for start, end, a_win, b_win in _merge_sorted_windows(gen_a, gen_b):
-            pairs: List[Tuple[int, int]] = []
-            if a_win is not None and b_win is not None:
-                idx_a, batch_a = a_win
-                idx_b, batch_b = b_win
-                for ai, bi in self._join_pairs(batch_a, batch_b, radius):
-                    pairs.extend(
-                        (int(idx_a[i]), int(idx_b[j]))
-                        for i, j in zip(ai.tolist(), bi.tolist())
-                        if i < len(idx_a) and j < len(idx_b)
-                    )
-            yield WindowResult(start, end, pairs)
 
     def _join_pairs(self, batch_a, batch_b, radius, window=None):
         """(a_index, b_index) survivor arrays for one window's pair lattice.

@@ -173,34 +173,9 @@ class PointPointKNNQuery(SpatialOperator):
 
         return self._stream_dispatch(batch, local, on_mesh)
 
-    def run_bulk(self, parsed, query_point: Point, radius: float,
-                 k: Optional[int] = None, *, pad: Optional[int] = None
-                 ) -> Iterator[WindowResult]:
-        """Bulk-replay fast path over vectorized window batches; records are
-        (objID, distance) pairs resolved through the parse-time interner."""
-        k = k or self.conf.k
-
-        def eval_batch(payload, ts_base):
-            _idx, batch = payload
-            res, dist_evals = self._knn_result(batch, query_point, radius, k)
-            d = self._defer_knn(res, interner=parsed.interner,
-                                dist_evals=dist_evals)
-            d.interner = parsed.interner
-            return d
-
-        for result in self._drive_bulk(
-                parsed, eval_batch, pad=pad,
-                pane_merge=lambda parts: merge_partials(parts, k,
-                                                        parsed.interner),
-                pane_device_merge=_knn_device_merge(self, k,
-                                                    parsed.interner)):
-            result.extras["k"] = k
-            yield result
-
     def _multi_local(self, query_points, radius: float, k: int):
         """The per-batch multi-kernel closure shared by run_multi and
-        run_multi_bulk — one definition so the stream and bulk paths cannot
-        fork."""
+        run_dynamic."""
         from spatialflink_tpu.ops.knn import knn_point_multi_stats
 
         qx, qy, qc = self._query_point_arrays(query_points)
@@ -335,27 +310,6 @@ class PointPointKNNQuery(SpatialOperator):
             result.extras["k"] = k
             yield result
 
-    def _bulk_batches(self, parsed, pad):
-        from spatialflink_tpu.streams.bulk import bulk_window_batches
-
-        return bulk_window_batches(parsed, self.conf.window_spec(),
-                                   self.grid, pad=pad)
-
-    def run_multi_bulk(self, parsed, query_points, radius: float,
-                       k: Optional[int] = None, *, pad: Optional[int] = None
-                       ) -> Iterator[WindowResult]:
-        """Bulk-replay multi-query (the ``--bulk --multi-query`` path) —
-        the shared base driver over point-stream windows."""
-        k = k or self.conf.k
-        batched = (
-            (start, end, (idx, batch))
-            for start, end, idx, batch in self._bulk_batches(parsed, pad)
-        )
-        return self._run_multi_knn_bulk(
-            batched, len(query_points),
-            self._multi_local(query_points, radius, k), k, parsed.interner)
-
-
 
 class _GenericKnn(SpatialOperator, GeomQueryMixin):
     telemetry_label = "knn"
@@ -375,9 +329,9 @@ class _GenericKnn(SpatialOperator, GeomQueryMixin):
 
     def _knn_eval(self, batch, elig_dists, k: int):
         """(KnnResult, dist_evals) over one batch — THE single kNN
-        evaluation body shared by run() and run_bulk(): distributed runs
-        the same closure per shard, single-device goes through the
-        module-jitted knn_eligible_stats."""
+        evaluation body of run(): distributed runs the same closure per
+        shard, single-device goes through the module-jitted
+        knn_eligible_stats."""
         def single(b):
             from spatialflink_tpu.ops.knn import knn_eligible_stats
 
@@ -420,38 +374,6 @@ class _GenericKnn(SpatialOperator, GeomQueryMixin):
             result.extras["k"] = k
             yield result
 
-    def run_bulk(self, parsed, query, radius: float,
-                 k: Optional[int] = None, *, pad: Optional[int] = None
-                 ) -> Iterator[WindowResult]:
-        """Bulk-replay fast path: vectorized window batches (points via
-        ``bulk_window_batches``, geometry streams via
-        ``bulk_geom_window_batches``) through the same eligibility/distance
-        closures; records are (objID, distance) pairs resolved through the
-        parse-time interner."""
-        k = k or self.conf.k
-        setup = self._setup(query, radius)
-
-        def elig_dists(batch):
-            return self._elig_dists(batch, setup)
-
-        def eval_batch(payload, ts_base):
-            _idx, batch = payload
-            res, dist_evals = self._knn_eval(batch, elig_dists, k)
-            return self._defer_knn(res, interner=parsed.interner,
-                                   dist_evals=dist_evals)
-
-        batched = (
-            (start, end, (idx, batch))
-            for start, end, idx, batch in self._bulk_batches(parsed, pad)
-        )
-        for result in self._drive_batched(batched, eval_batch,
-                                          count=lambda p: len(p[0])):
-            result.extras["k"] = k
-            yield result
-
-    def _bulk_batches(self, parsed, pad):
-        raise NotImplementedError
-
     def _drive_multi(self, stream, n_queries: int, local, k: int
                      ) -> Iterator[WindowResult]:
         """Shared run_multi loop: ``local(batch)`` is the class's
@@ -489,35 +411,13 @@ class _GenericKnn(SpatialOperator, GeomQueryMixin):
         return self._drive_multi(stream, len(queries),
                                  self._multi_local(queries, radius, k), k)
 
-    def run_multi_bulk(self, parsed, queries, radius: float,
-                       k: Optional[int] = None, *, pad: Optional[int] = None
-                       ) -> Iterator[WindowResult]:
-        """Bulk-replay multi-query over this class's vectorized window
-        source (the ``--bulk --multi-query`` path for the geometry pairs)."""
-        k = k or self.conf.k
-        batched = (
-            (start, end, (idx, batch))
-            for start, end, idx, batch in self._bulk_batches(parsed, pad)
-        )
-        return self._run_multi_knn_bulk(
-            batched, len(queries), self._multi_local(queries, radius, k), k,
-            parsed.interner)
-
 
 class _GeomStreamKnn(_GenericKnn):
-    """Geometry-stream kNN base: EdgeGeomBatch construction + the
-    mesh-divisible bulk window source (shared by GeomPoint and GeomGeom)."""
+    """Geometry-stream kNN base: EdgeGeomBatch construction (shared by
+    GeomPoint and GeomGeom)."""
 
     def _batch(self, records, ts_base):
         return self._geom_batch(records, ts_base)
-
-    def _bulk_batches(self, parsed, pad):
-        from spatialflink_tpu.streams.bulk import bulk_geom_window_batches
-
-        min_bucket = max(8, self.conf.devices) if self.distributed else 8
-        return bulk_geom_window_batches(parsed, self.conf.window_spec(),
-                                        self.grid, pad=pad,
-                                        min_bucket=min_bucket)
 
 
 class PointGeomKNNQuery(_GenericKnn):
@@ -547,12 +447,6 @@ class PointGeomKNNQuery(_GenericKnn):
 
     def _batch(self, records, ts_base):
         return self._point_batch(records, ts_base)
-
-    def _bulk_batches(self, parsed, pad):
-        from spatialflink_tpu.streams.bulk import bulk_window_batches
-
-        return bulk_window_batches(parsed, self.conf.window_spec(),
-                                   self.grid, pad=pad)
 
     def _elig_dists(self, batch, setup):
         from spatialflink_tpu.ops.distances import point_bbox_dist

@@ -8,7 +8,7 @@ points pass iff exact distance <= r; approximate mode emits all GN∪CN points.
 
 from __future__ import annotations
 
-from typing import Iterable, Iterator, List, Optional
+from typing import Iterable, Iterator, List
 
 import jax.numpy as jnp
 
@@ -23,40 +23,7 @@ from spatialflink_tpu.operators.base import (
 from spatialflink_tpu.ops.range import range_filter_point_stats
 
 
-class _RangeMultiBulkMixin:
-    """One run_multi_bulk body for every range pair: subclasses provide
-    the window source (:meth:`_bulk_batches`) and the per-class multi-mask
-    closure (:meth:`_multi_mask_stats`)."""
-
-    def _bulk_batches(self, parsed, pad):
-        raise NotImplementedError
-
-    def run_multi_bulk(self, parsed, queries, radius: float, *,
-                       pad: Optional[int] = None) -> Iterator[WindowResult]:
-        """Bulk-replay multi-query (the ``--bulk --multi-query`` path):
-        per-query original-record index lists from one (Q, N) mask dispatch
-        per window."""
-        batched = (
-            (start, end, (idx, batch))
-            for start, end, idx, batch in self._bulk_batches(parsed, pad)
-        )
-        return self._run_multi_filter_bulk(
-            batched, len(queries), self._multi_mask_stats(queries, radius))
-
-
-class _PointStreamBulkSource:
-    """Point-stream bulk window source shared by the point-stream range
-    classes' multi-bulk paths."""
-
-    def _bulk_batches(self, parsed, pad):
-        from spatialflink_tpu.streams.bulk import bulk_window_batches
-
-        return bulk_window_batches(parsed, self.conf.window_spec(),
-                                   self.grid, pad=pad)
-
-
-class PointPointRangeQuery(_PointStreamBulkSource, _RangeMultiBulkMixin,
-                           SpatialOperator):
+class PointPointRangeQuery(SpatialOperator):
     telemetry_label = "range"
 
     #: pane-incremental hooks (``--panes``): the window evaluator IS the
@@ -126,21 +93,9 @@ class PointPointRangeQuery(_PointStreamBulkSource, _RangeMultiBulkMixin,
 
     # ---------------------------------------------------------------- #
 
-    def run_bulk(self, parsed, query_point: Point, radius: float, *,
-                 pad: Optional[int] = None) -> Iterator[WindowResult]:
-        """Bulk-replay fast path: windows come from the vectorized assembler
-        (``streams.bulk.bulk_window_batches``) and results are original-record
-        index lists — no per-record Python objects anywhere.
-
-        Windowed mode only (a bounded replay has no realtime trigger).
-        """
-        return self._drive_bulk(
-            parsed, self._bulk_mask_eval(self._mask_stats_fn(query_point, radius)),
-            pad=pad, pane_merge=self.merge_partials)
-
     def _multi_mask_stats(self, query_points, radius: float):
         """The per-batch multi-mask closure shared by run_multi and
-        run_multi_bulk."""
+        run_dynamic."""
         from spatialflink_tpu.ops.range import range_filter_point_multi_masks
 
         qx, qy, qc = self._query_point_arrays(query_points)
@@ -225,8 +180,7 @@ class PointPointRangeQuery(_PointStreamBulkSource, _RangeMultiBulkMixin,
             yield WindowResult(start, end, list(out.values()))
 
 
-class PointGeomRangeQuery(_PointStreamBulkSource, _RangeMultiBulkMixin,
-                          SpatialOperator, GeomQueryMixin):
+class PointGeomRangeQuery(SpatialOperator, GeomQueryMixin):
     telemetry_label = "range"
 
     merge_partials = staticmethod(SpatialOperator._pane_concat)
@@ -285,14 +239,6 @@ class PointGeomRangeQuery(_PointStreamBulkSource, _RangeMultiBulkMixin,
 
         return self._drive(stream, eval_batch, pane_merge=self.merge_partials)
 
-    def run_bulk(self, parsed, query_geom, radius: float, *,
-                 pad: Optional[int] = None) -> Iterator[WindowResult]:
-        """Bulk-replay fast path over point-stream windows (native ingest;
-        results are original-record index lists)."""
-        return self._drive_bulk(
-            parsed, self._bulk_mask_eval(self._mask_stats_fn(query_geom, radius)),
-            pad=pad, pane_merge=self.merge_partials)
-
     def _multi_mask_stats(self, query_geoms, radius: float):
         from spatialflink_tpu.ops.geom import range_points_to_geom_queries
 
@@ -317,35 +263,7 @@ class PointGeomRangeQuery(_PointStreamBulkSource, _RangeMultiBulkMixin,
             self._point_batch, leaf_mask_builder=union_leaf_mask)
 
 
-class _GeomStreamBulkMixin:
-    """Bulk-replay fast path for geometry STREAMS: native WKT ingest ->
-    vectorized window assembly (``streams.bulk.bulk_geom_window_batches``)
-    -> the operator's own mask_stats kernels; results are original-record
-    index lists, no per-record Python objects."""
-
-    def _bulk_batches(self, parsed, pad):
-        from spatialflink_tpu.streams.bulk import bulk_geom_window_batches
-
-        # like base._geom_batch: the geometry dim must divide across the
-        # mesh, so the per-window bucket floor rises to the device count
-        min_bucket = max(8, self.conf.devices) if self.distributed else 8
-        return bulk_geom_window_batches(parsed, self.conf.window_spec(),
-                                        self.grid, pad=pad,
-                                        min_bucket=min_bucket)
-
-    def run_bulk(self, parsed, query, radius: float, *,
-                 pad: Optional[int] = None) -> Iterator[WindowResult]:
-        batched = (
-            (start, end, (idx, batch))
-            for start, end, idx, batch in self._bulk_batches(parsed, pad)
-        )
-        return self._drive_batched(
-            batched, self._bulk_mask_eval(self._mask_stats_fn(query, radius)),
-            count=lambda p: len(p[0]))
-
-
-class GeomPointRangeQuery(SpatialOperator, GeomQueryMixin,
-                          _GeomStreamBulkMixin, _RangeMultiBulkMixin):
+class GeomPointRangeQuery(SpatialOperator, GeomQueryMixin):
     telemetry_label = "range"
 
     merge_partials = staticmethod(SpatialOperator._pane_concat)
@@ -423,8 +341,7 @@ class GeomPointRangeQuery(SpatialOperator, GeomQueryMixin,
             self._geom_batch)
 
 
-class GeomGeomRangeQuery(SpatialOperator, GeomQueryMixin,
-                         _GeomStreamBulkMixin, _RangeMultiBulkMixin):
+class GeomGeomRangeQuery(SpatialOperator, GeomQueryMixin):
     telemetry_label = "range"
 
     merge_partials = staticmethod(SpatialOperator._pane_concat)

@@ -391,8 +391,8 @@ def lat_sidecar(budget_row: Optional[dict]) -> Optional[dict]:
     ingest/emit wall stamps plus the CHAIN stages only, so the
     supervisor can extend the chain with ``outbox-visible -> merge ->
     merged-emit`` and keep the sums-to-total invariant end to end.
-    Returns None for windows without an ingest stamp (bulk batches) —
-    they cannot anchor a record→merged-emit measurement."""
+    Returns None for windows without an ingest stamp — they cannot
+    anchor a record→merged-emit measurement."""
     if not budget_row or budget_row.get("first_ingest_ms") is None:
         return None
     stages = budget_row.get("stages") or {}

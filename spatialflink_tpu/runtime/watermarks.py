@@ -29,18 +29,3 @@ class BoundedOutOfOrderness:
         already have fired)."""
         return ts_ms < self.watermark
 
-    @staticmethod
-    def bulk_keep_mask(ts_ms, allowed_lateness_ms: int = 0):
-        """Vectorized twin of the add-time late check: ``keep[i]`` is False
-        iff record i would be dropped by ``is_late`` when the stream is fed
-        in array order (watermark = running max of *earlier* records minus
-        the allowed lateness). Lets bulk replays reproduce the record path's
-        lateness semantics without a per-record loop."""
-        import numpy as np
-
-        ts = np.asarray(ts_ms, np.int64)
-        keep = np.ones(ts.shape[0], bool)
-        if ts.shape[0] > 1:
-            prev_max = np.maximum.accumulate(ts)[:-1]
-            keep[1:] = ts[1:] >= prev_max - int(allowed_lateness_ms)
-        return keep

@@ -2,8 +2,8 @@
 
 The per-tuple path (``streams.formats.parse_spatial``) mirrors the
 reference's per-record deserializer; this module is the high-throughput
-twin used when a whole file/window of records is available at once — the
-common replay/benchmark case, and what a Kafka poll returns. The parse runs
+twin that decodes a whole chunk of records at once — every file replay
+chunk and every Kafka poll on the served path. The parse runs
 in native C++ (:mod:`spatialflink_tpu.native`), known obj ids resolve
 through the interner's hash index (strings only for unseen ids), and only
 rejected lines (ISO dates, non-point GeoJSON, malformed rows) fall back
@@ -576,107 +576,6 @@ def bulk_parse_geojson(
     return _merge_rejects(n, accepted, reparsed, interner)
 
 
-def bulk_window_batches(parsed: ParsedPoints, spec, grid=None, *,
-                        pad: Optional[int] = None):
-    """Vectorized window assembly: ParsedPoints -> per-window device batches.
-
-    Yields ``(start, end, idx, PointBatch)`` in window order, where ``idx``
-    is the original-record index array for the window. The whole assignment
-    is numpy (``WindowSpec.assign_bulk``); batches are built straight from
-    the SoA slices, so no per-record Python objects exist anywhere on this
-    path — the high-throughput twin of ``WindowAssembler`` for bounded
-    replays, mirroring how ``bulk_parse_*`` twins ``formats.parse_spatial``.
-    """
-    if not len(parsed):
-        return
-    win, rec = spec.assign_bulk(parsed.ts)
-    if not len(win):  # sampling specs (slide > size) can assign nothing
-        return
-    # cells once per record, not once per window membership (sliding windows
-    # revisit each record size/slide times)
-    if grid is not None:
-        cells, _ = grid.assign_cell(parsed.x, parsed.y)
-        cells = np.asarray(cells, np.int32)
-    else:
-        cells = np.full(len(parsed), -1, np.int32)
-    bounds = np.flatnonzero(np.r_[True, win[1:] != win[:-1], True])
-    for i in range(len(bounds) - 1):
-        lo, hi = int(bounds[i]), int(bounds[i + 1])
-        start = int(win[lo])
-        idx = rec[lo:hi]
-        batch = PointBatch.from_arrays(
-            parsed.x[idx], parsed.y[idx], grid=grid,
-            obj_id=parsed.obj_id[idx], ts=parsed.ts[idx],
-            ts_base=start, pad=pad, cell=cells[idx],
-        )
-        yield start, start + spec.size_ms, idx, batch
-
-
-def bulk_pane_window_batches(parsed: ParsedPoints, spec, grid=None, *,
-                             pad: Optional[int] = None):
-    """Pane-sliced twin of :func:`bulk_window_batches` for the
-    ``--panes`` execution mode: each record lands in exactly ONE
-    slide-aligned pane batch (built once — not ``size/slide`` times), and
-    windows are yielded as ``(start, end, [(pane_start, (idx, batch)),
-    ...])`` pane lists covering the same window set ``assign_bulk`` would
-    produce. Requires ``spec.pane_decomposable()`` (callers gate)."""
-    if not len(parsed):
-        return
-    size, slide = spec.size_ms, spec.slide_ms
-    ts = np.asarray(parsed.ts, np.int64)
-    pane = ts - ts % slide
-    order = np.argsort(pane, kind="stable")  # record order kept within pane
-    pane_s = pane[order]
-    if grid is not None:
-        cells, _ = grid.assign_cell(parsed.x, parsed.y)
-        cells = np.asarray(cells, np.int32)
-    else:
-        cells = np.full(len(parsed), -1, np.int32)
-    bounds = np.flatnonzero(np.r_[True, pane_s[1:] != pane_s[:-1], True])
-    # index slices now (cheap views of `order`); pane BATCHES build lazily
-    # on first use and evict once no later window can cover them, so peak
-    # host memory is O(overlap panes), not a second full copy of the replay
-    slices = {int(pane_s[int(bounds[i])]):
-              order[int(bounds[i]): int(bounds[i + 1])]
-              for i in range(len(bounds) - 1)}
-    built: dict = {}
-    # window set: every aligned start covered by >= 1 non-empty pane — the
-    # same set assign_bulk derives record-by-record
-    starts = sorted({int(s)
-                     for p in slices
-                     for s in range(p - size + slide, p + slide, slide)})
-    for s in starts:
-        panes = []
-        for p in range(s, s + size, slide):
-            idx = slices.get(p)
-            if idx is None:
-                continue
-            batch = built.get(p)
-            if batch is None:
-                batch = built[p] = PointBatch.from_arrays(
-                    parsed.x[idx], parsed.y[idx], grid=grid,
-                    obj_id=parsed.obj_id[idx], ts=parsed.ts[idx],
-                    ts_base=p, pad=pad, cell=cells[idx],
-                )
-            panes.append((p, (idx, batch)))
-        for dead in [p for p in built if p < s + slide]:
-            del built[dead]
-        yield s, s + size, panes
-
-
-def bulk_parse_file(path: str, fmt: str, **kw) -> ParsedPoints:
-    """Bulk-parse a whole replay file of points."""
-    with open(path, "rb") as f:
-        data = f.read()
-    if fmt.lower() in ("csv", "tsv"):
-        if fmt.lower() == "tsv":
-            kw.setdefault("delimiter", "\t")
-        return bulk_parse_csv(data, **kw)
-    if fmt.lower() == "geojson":
-        return bulk_parse_geojson(data, **kw)
-    raise ValueError(f"bulk ingestion supports csv/tsv/geojson, not {fmt!r}")
-
-
 # --------------------------------------------------------------------------- #
 # Bulk WKT geometry ingestion (polygon / linestring streams)
 
@@ -1089,44 +988,3 @@ def geoms_to_edge_batch(parsed: ParsedGeoms, grid=None, *,
         is_areal=pad_to(parsed.is_areal, size),
         valid=pad_to(np.ones(n, bool), size),
     )
-
-
-def bulk_geom_window_batches(parsed: ParsedGeoms, spec, grid=None, *,
-                             pad: Optional[int] = None,
-                             min_bucket: int = 8):
-    """Vectorized window assembly for geometry streams:
-    ParsedGeoms -> per-window (start, end, idx, EdgeGeomBatch) — the
-    geometry twin of :func:`bulk_window_batches`. ``min_bucket`` raises the
-    per-window capacity floor (mesh runs need the geometry dim divisible by
-    the device count)."""
-    from spatialflink_tpu.utils.padding import bucket_size
-
-    if not len(parsed):
-        return
-    win, rec = spec.assign_bulk(parsed.ts)
-    if not len(win):
-        return
-    bounds = np.flatnonzero(np.r_[True, win[1:] != win[:-1], True])
-    for i in range(len(bounds) - 1):
-        lo, hi = int(bounds[i]), int(bounds[i + 1])
-        start = int(win[lo])
-        idx = rec[lo:hi]
-        wpad = pad if pad is not None else bucket_size(idx.size, min_bucket)
-        batch = geoms_to_edge_batch(parsed.subset(idx), grid,
-                                    ts_base=start, pad=wpad)
-        yield start, start + spec.size_ms, idx, batch
-
-
-def bulk_parse_geom_file(path: str, fmt: str = "WKT", **kw) -> ParsedGeoms:
-    """Bulk-parse a whole replay file of WKT or GeoJSON polygon/linestring
-    records (kwargs are format-specific: delimiter/date_format for WKT,
-    property_obj_id/property_timestamp/date_format for GeoJSON)."""
-    f = fmt.lower()
-    if f not in ("wkt", "geojson"):
-        raise ValueError(
-            f"bulk geometry ingestion supports WKT/GeoJSON, not {fmt!r}")
-    with open(path, "rb") as fh:
-        data = fh.read()
-    if f == "wkt":
-        return bulk_parse_wkt(data, **kw)
-    return bulk_parse_geojson_geoms(data, **kw)

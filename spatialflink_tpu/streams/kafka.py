@@ -124,8 +124,8 @@ def resequence_batch(batch: List[BrokerRecord], next_offset: int
     batch by offset and drop records already delivered (offset below
     ``next_offset``) or re-delivered within the batch. What a real
     consumer's fetch-session dedup does; a no-op on clean transports.
-    Shared by :class:`KafkaSource` and the driver's ``--bulk`` topic drain
-    — both assume offset-ordered, exactly-once-per-position hand-off."""
+    :class:`KafkaSource` assumes offset-ordered, exactly-once-per-position
+    hand-off."""
     # fast path: a clean transport delivers the batch already contiguous
     # from next_offset — one scan, no sort, no copy (the common case on
     # every poll of an undegraded broker)
@@ -474,7 +474,7 @@ class WindowCommitTap:
 
     ``bulk_decode`` (optional) batches the per-record parse through the
     native ingest: raw string records accumulate into chunks and decode in
-    ONE native call (the bulk replay path's parser, applied to broker
+    ONE native call (the columnar point parser, applied to broker
     records) — per-record positions are snapshotted at pull time, so the
     window-aligned commit bookkeeping is identical. In live mode the source
     must be constructed with ``starvation_sentinel=True``: the tap flushes

@@ -170,8 +170,8 @@ class LatencyPlane:
         """One emitted window's full budget: ``stages`` are the chain
         durations in ms (consecutive intervals — their sum IS the
         record→emit latency when the ingest stamp exists; payloads without
-        one, e.g. bulk replay batches, feed the stage histograms but skip
-        the record→emit observation)."""
+        one feed the stage histograms but skip the record→emit
+        observation)."""
         ws = int(window_start)
         with self._lock:
             self._inflight.pop(ws, None)

@@ -204,3 +204,48 @@ def knn(qx, qy, xs, ys, obj_ids, k, radius=None):
             best[oid] = dist
     items = sorted(best.items(), key=lambda kv: kv[1])[:k]
     return [o for o, _ in items], [float(v) for _, v in items]
+
+
+def range_window_table(rows, qx, qy, radius, size, slide, lateness=0):
+    """Per-window exact range answer over ``rows`` — ``(obj_id, ts, x, y)``
+    tuples in arrival order: ``{window_start: sorted [(obj_id, ts)]}`` of
+    the records within ``radius`` of (qx, qy), windows and late drops as
+    :func:`sliding_window_table`. Windows with no match are left out."""
+    table = sliding_window_table([r[1] for r in rows], size, slide, lateness)
+    out = {}
+    for start, idx in table.items():
+        hits = sorted((rows[i][0], int(rows[i][1])) for i in idx
+                      if pp_dist(qx, qy, rows[i][2], rows[i][3]) <= radius)
+        if hits:
+            out[start] = hits
+    return out
+
+
+def knn_window_table(rows, qx, qy, k, size, slide, lateness=0):
+    """Per-window exact kNN answer over ``(obj_id, ts, x, y)`` rows:
+    ``{window_start: (obj_ids, dists)}`` as :func:`knn` returns them."""
+    table = sliding_window_table([r[1] for r in rows], size, slide, lateness)
+    return {start: knn(qx, qy, [rows[i][2] for i in idx],
+                       [rows[i][3] for i in idx],
+                       [rows[i][0] for i in idx], k)
+            for start, idx in table.items()}
+
+
+def join_window_table(rows_a, rows_b, radius, size, slide):
+    """Per-window exact point-point join over two in-order row streams:
+    ``{window_start: sorted [((oid_a, ts_a), (oid_b, ts_b))]}`` of every
+    same-window pair within ``radius``. Windows with no pair are left
+    out."""
+    ta = sliding_window_table([r[1] for r in rows_a], size, slide)
+    tb = sliding_window_table([r[1] for r in rows_b], size, slide)
+    out = {}
+    for start in set(ta) & set(tb):
+        pairs = sorted(
+            ((rows_a[i][0], int(rows_a[i][1])),
+             (rows_b[j][0], int(rows_b[j][1])))
+            for i in ta[start] for j in tb[start]
+            if pp_dist(rows_a[i][2], rows_a[i][3],
+                       rows_b[j][2], rows_b[j][3]) <= radius)
+        if pairs:
+            out[start] = pairs
+    return out

@@ -94,7 +94,7 @@ def test_bench_kafka_smoke(tmp_path):
     assert r.returncode == 0, r.stderr[-2000:]
     rows = [json.loads(ln) for ln in r.stdout.splitlines()
             if ln.startswith("{")]
-    assert [x["path"] for x in rows] == ["record", "chunked", "bulk", "file"]
+    assert [x["path"] for x in rows] == ["record", "chunked", "file"]
     assert all(x["windows"] == rows[0]["windows"] > 0 for x in rows)
     assert json.load(open(out_path))["rows"]
 
@@ -111,22 +111,19 @@ def test_bench_e2e_smoke(tmp_path):
     assert r.returncode == 0, r.stderr[-2000:]
     rows = [json.loads(ln) for ln in r.stdout.splitlines()
             if ln.startswith("{")]
-    # both paths per option: the bulk fast path must stay reachable for
-    # range AND join (a silent fallback to record-only would hide a
-    # regression in run_option_bulk's eligibility gates); the multi rows
-    # cover the --multi-query --bulk composition end-to-end
+    # one served row per option (range AND join); the multi rows cover
+    # the --multi-query composition end-to-end
     assert [(x["option"], x["path"]) for x in rows] == [
-        (1, "bulk"), (1, "record"), (101, "bulk"), (101, "record"),
+        (1, "record"), (101, "record"),
         (1, "multi_query"), (1, "sequential_jobs")]
-    for row in rows[:4]:
+    for row in rows[:2]:
         assert row["records"] == 2000
         assert row["records_per_sec"] > 0
         assert row["windows"] > 0
-    # bulk and record paths agree on how many windows the stream seals
-    assert rows[0]["windows"] == rows[1]["windows"]
-    assert rows[2]["windows"] == rows[3]["windows"]
-    assert rows[4]["queries"] == 2
-    assert rows[4]["speedup_vs_sequential_jobs"] > 0
+    # the multi-query pipeline seals the single-query run's windows
+    assert rows[2]["windows"] == rows[0]["windows"]
+    assert rows[2]["queries"] == 2
+    assert rows[2]["speedup_vs_sequential_jobs"] > 0
     table = json.loads(out_path.read_text())
     assert table["rows"] and table["backend"] == "cpu"
 

@@ -97,7 +97,7 @@ def test_lat_sidecar_excluded_from_fingerprint_and_digest():
 def test_lat_sidecar_builder_filters_unusable_rows():
     assert F.lat_sidecar(None) is None
     assert F.lat_sidecar({}) is None
-    # bulk-replay budget rows without an ingest stamp carry no lineage
+    # budget rows without an ingest stamp carry no lineage
     assert F.lat_sidecar({"first_ingest_ms": None,
                           "stages": {"emit": 1.0}}) is None
     row = {"first_ingest_ms": 10.0, "emitted_ms": 30.0,

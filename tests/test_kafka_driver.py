@@ -301,93 +301,82 @@ def test_kafka_realtime_lagged_commits(tmp_path):
     assert 0 < committed < len(pts)
     cfg, _ = _conf(tmp_path, "reject")
     with pytest.raises(SystemExit):
-        main(["--config", cfg, "--kafka", "--bulk", "--kafka-follow"])
-    with pytest.raises(SystemExit):
         main(["--config", cfg, "--kafka", "--option", "99"])
 
 
-def test_kafka_bulk_topic_replay(tmp_path):
-    """--kafka --bulk: the topic drains once through the native bulk path;
-    marker-keyed windows match the streaming broker run record for record,
-    and the drained offsets commit (a re-run replays nothing)."""
+def _file_table(capsys, cfg, argv, *inputs):
+    """{window key: marker value} the broker path must produce for the
+    same case, derived from the file replay's window summaries: the
+    window's record count, plus the one JSON summary record the sink adds
+    for a window that carries extras (kNN's k, multi-query metadata)."""
+    import ast
+
+    for flag, path in zip(("--input1", "--input2"), inputs):
+        argv = argv + [flag, str(path)]
+    assert main(["--config", cfg] + argv) == 0
+    rows = [ast.literal_eval(ln) for ln in
+            capsys.readouterr().out.splitlines() if ln.startswith("{")]
+    summary = {"window", "count", "per_query_counts"}
+    return {f"{r['window'][0]}:{r['window'][1]}:None":
+            r["count"] + (1 if set(r) - summary else 0) for r in rows}
+
+
+def _marker_table(broker):
+    return {_strip_job(r.key[len(KafkaWindowSink.MARKER):]): int(r.value)
+            for r in broker.fetch(OUT, 0, 1_000_000)
+            if isinstance(r.key, str)
+            and r.key.startswith(KafkaWindowSink.MARKER)}
+
+
+def _write(tmp_path, name, lines):
+    f = tmp_path / name
+    f.write_text("\n".join(lines) + "\n")
+    return f
+
+
+def test_kafka_bulk_topic_replay(tmp_path, capsys):
+    """A bounded topic replay: every window's record count equals the file
+    replay's, the group commits the whole topic, and a re-run replays
+    nothing and adds no window."""
     lines = _lines()
-    cfg_s, url_s = _conf(tmp_path, "bulkdrain-stream", "cs.yml")
-    bs = resolve_broker(url_s)
-    cfg_b, url_b = _conf(tmp_path, "bulkdrain-bulk", "cb.yml")
-    bb = resolve_broker(url_b)
+    cfg, url = _conf(tmp_path, "topic-replay")
+    want = _file_table(capsys, cfg, ["--option", "1"],
+                       _write(tmp_path, "in.geojson", lines))
+    broker = resolve_broker(url)
     for ln in lines:
-        bs.produce(IN1, ln)
-        bb.produce(IN1, ln)
-    assert main(["--config", cfg_s, "--kafka", "--option", "1"]) == 0
-    assert main(["--config", cfg_b, "--kafka", "--option", "1",
-                 "--bulk"]) == 0
-
-    def window_table(broker):
-        out = {}
-        for r in broker.fetch(OUT, 0, 1_000_000):
-            if isinstance(r.key, str) and r.key.startswith(
-                    KafkaWindowSink.MARKER):
-                out[r.key[len(KafkaWindowSink.MARKER):]] = int(r.value)
-        return out
-
-    assert window_table(bb) == window_table(bs)
-    assert window_table(bb), "no windows produced"
-    assert bb.committed(IN1, "spatialflink") == len(lines)
-    # drained offsets committed: a re-run finds nothing new and suppresses
-    assert main(["--config", cfg_b, "--kafka", "--option", "1",
-                 "--bulk"]) == 0
-    assert window_table(bb) == window_table(bs)
-
-
-def test_kafka_bulk_join_two_topics(tmp_path):
-    """Join (101) through the topic drain: both topics drain, pair counts
-    match the streaming broker run, both groups commit."""
-    lines = _lines()
-    cfg_s, url_s = _conf(tmp_path, "bj-s", "cs.yml")
-    bs = resolve_broker(url_s)
-    cfg_b, url_b = _conf(tmp_path, "bj-b", "cb.yml")
-    bb = resolve_broker(url_b)
-    for ln in lines:
-        bs.produce(IN1, ln)
-        bb.produce(IN1, ln)
-    for ln in _lines(seed=8):
-        bs.produce(IN2, ln)
-        bb.produce(IN2, ln)
-    assert main(["--config", cfg_s, "--kafka", "--option", "101"]) == 0
-    assert main(["--config", cfg_b, "--kafka", "--option", "101",
-                 "--bulk"]) == 0
-    assert sorted(_markers(bb)) == sorted(_markers(bs))
-    assert bb.committed(IN1, "spatialflink") == len(lines)
-    assert bb.committed(IN2, "spatialflink") == bb.end_offset(IN2)
-
-
-def test_kafka_bulk_gates_before_draining(tmp_path, capsys):
-    """An invocation the cheap case gates reject (COUNT windows) never pays
-    the topic drain — the 'not bulk-drainable' reader message must NOT
-    appear, only the early 'not applicable' one."""
-    import yaml as _yaml
-
-    with open(CONF) as f:
-        d = _yaml.safe_load(f)
-    d["kafkaBootStrapServers"] = "memory://gate"
-    d["window"] = {"type": "COUNT", "interval": 16, "step": 8}
-    p = tmp_path / "count.yml"
-    p.write_text(_yaml.safe_dump(d))
-    broker = resolve_broker("memory://gate")
-    for ln in _lines():
         broker.produce(IN1, ln)
-    rc = main(["--config", str(p), "--kafka", "--option", "1", "--bulk"])
-    assert rc == 0
-    err = capsys.readouterr().err
-    assert "not applicable" in err
-    assert "not bulk-drainable" not in err
+    assert main(["--config", cfg, "--kafka", "--option", "1"]) == 0
+    assert _marker_table(broker) == want != {}
+    assert broker.committed(IN1, "spatialflink") == len(lines)
+    marks = sorted(_markers(broker))
+    assert main(["--config", cfg, "--kafka", "--option", "1"]) == 0
+    assert sorted(_markers(broker)) == marks
+
+
+def test_kafka_bulk_join_two_topics(tmp_path, capsys):
+    """Join (101) over two topics: per-window pair counts equal the
+    two-file replay's, and both groups commit their whole topic."""
+    lines, lines2 = _lines(), _lines(seed=8)
+    cfg, url = _conf(tmp_path, "join-topics")
+    want = _file_table(capsys, cfg, ["--option", "101"],
+                       _write(tmp_path, "a.geojson", lines),
+                       _write(tmp_path, "b.geojson", lines2))
+    broker = resolve_broker(url)
+    for ln in lines:
+        broker.produce(IN1, ln)
+    for ln in lines2:
+        broker.produce(IN2, ln)
+    assert main(["--config", cfg, "--kafka", "--option", "101"]) == 0
+    assert _marker_table(broker) == want != {}
+    assert broker.committed(IN1, "spatialflink") == len(lines)
+    assert broker.committed(IN2, "spatialflink") == len(lines2)
 
 
 def test_kafka_mixed_geometry_record_resilience(tmp_path, capsys):
     """A stray polygon feature in a declared point topic must not crash
-    either kafka mode: the chunked decode falls back to the per-record
-    parse (which dead-letters the off-type record), and --bulk falls back
-    to the streaming path — both keep producing windows."""
+    the broker path: the chunked decode falls back to the per-record
+    parse (which dead-letters the off-type record) and keeps producing
+    windows."""
     poly = json.dumps({
         "geometry": {"type": "Polygon", "coordinates":
                      [[[116.2, 40.2], [116.4, 40.2], [116.4, 40.4],
@@ -395,43 +384,38 @@ def test_kafka_mixed_geometry_record_resilience(tmp_path, capsys):
         "properties": {"oID": "px", "timestamp": 1_700_000_003_000}})
     lines = _lines()
     records = lines[:15] + [poly] + lines[15:]
-    for mode, extra in (("mixed-stream", []), ("mixed-bulk", ["--bulk"])):
-        cfg, url = _conf(tmp_path, mode, f"{mode}.yml")
-        broker = resolve_broker(url)
-        for r in records:
-            broker.produce(IN1, r)
-        rc = main(["--config", cfg, "--kafka", "--option", "1"] + extra)
-        assert rc == 0, mode
-        assert _markers(broker), mode
-        assert broker.committed(IN1, "spatialflink") == len(records), mode
+    cfg, url = _conf(tmp_path, "mixed-stream", "mixed-stream.yml")
+    broker = resolve_broker(url)
+    for r in records:
+        broker.produce(IN1, r)
+    rc = main(["--config", cfg, "--kafka", "--option", "1"])
+    assert rc == 0
+    assert _markers(broker)
+    assert broker.committed(IN1, "spatialflink") == len(records)
 
 
-def test_kafka_bulk_composes_with_multi_query(tmp_path):
-    """--kafka --bulk --multi-query: the lazy topic drain feeds the bulk
-    multi-query evaluators; markers match the streaming multi run."""
+def test_kafka_bulk_composes_with_multi_query(tmp_path, capsys):
+    """--kafka --multi-query (kNN, two query points): per-window counts
+    equal the file replay's under the same flag."""
     qp = {"queryPoints": [[116.3, 40.3], [116.7, 40.7]]}
     lines = _lines()
-    cfg_s, url_s = _conf(tmp_path, "mqb-s", "cs.yml", **qp)
-    bs = resolve_broker(url_s)
-    cfg_b, url_b = _conf(tmp_path, "mqb-b", "cb.yml", **qp)
-    bb = resolve_broker(url_b)
+    cfg, url = _conf(tmp_path, "multi-topic", **qp)
+    argv = ["--option", "51", "--multi-query"]
+    want = _file_table(capsys, cfg, argv,
+                       _write(tmp_path, "in.geojson", lines))
+    broker = resolve_broker(url)
     for ln in lines:
-        bs.produce(IN1, ln)
-        bb.produce(IN1, ln)
-    assert main(["--config", cfg_s, "--kafka", "--option", "51",
-                 "--multi-query"]) == 0
-    assert main(["--config", cfg_b, "--kafka", "--option", "51",
-                 "--multi-query", "--bulk"]) == 0
-    assert sorted(_markers(bb)) == sorted(_markers(bs)) != []
-    assert bb.committed(IN1, "spatialflink") == len(lines)
+        broker.produce(IN1, ln)
+    assert main(["--config", cfg, "--kafka"] + argv) == 0
+    assert _marker_table(broker) == want != {}
+    assert broker.committed(IN1, "spatialflink") == len(lines)
 
 
-def test_kafka_bulk_geometry_stream(tmp_path):
-    """A WKT polygon STREAM (option 21, polygon-point range) drains through
-    the geometry bulk path; markers match the streaming broker run."""
+def test_kafka_bulk_geometry_stream(tmp_path, capsys):
+    """A WKT polygon STREAM (option 21, polygon-polygon range) over the
+    broker: per-window counts equal the file replay's."""
     import numpy as np
 
-    grid = UniformGrid(115.5, 117.6, 39.6, 41.1, num_grid_partitions=100)
     rng = np.random.default_rng(3)
     t0 = 1_700_000_000_000
     rows = []
@@ -442,35 +426,15 @@ def test_kafka_bulk_geometry_stream(tmp_path):
                     f"{cx - w} {cy - w}, {cx + w} {cy - w}, "
                     f"{cx + w} {cy + w}, {cx - w} {cy + w}, "
                     f"{cx - w} {cy - w}))")
-    cfg_s, url_s = _conf(tmp_path, "geo-s", "cs.yml")
-    bs = resolve_broker(url_s)
-    cfg_b, url_b = _conf(tmp_path, "geo-b", "cb.yml")
-    bb = resolve_broker(url_b)
-    for r in rows:
-        bs.produce(IN1, r)
-        bb.produce(IN1, r)
-    argv = ["--kafka", "--option", "21", "--format", "WKT"]
-    assert main(["--config", cfg_s] + argv) == 0
-    assert main(["--config", cfg_b] + argv + ["--bulk"]) == 0
-    assert sorted(_markers(bb)) == sorted(_markers(bs)) != []
-    assert bb.committed(IN1, "spatialflink") == len(rows)
-
-
-def test_kafka_bulk_bails_on_control_tuple(tmp_path, capsys):
-    """A control tuple in the topic makes the drain bail to the streaming
-    path, which honors the stop semantics."""
-    cfg, url = _conf(tmp_path, "bulk-control")
+    cfg, url = _conf(tmp_path, "geo-topic")
+    argv = ["--option", "21", "--format", "WKT"]
+    want = _file_table(capsys, cfg, argv, _write(tmp_path, "in.wkt", rows))
     broker = resolve_broker(url)
-    lines = _lines()
-    for ln in lines[:20]:
-        broker.produce(IN1, ln)
-    broker.produce(IN1, json.dumps(
-        {"geometry": {"type": "control", "coordinates": []}}))
-    rc = main(["--config", cfg, "--kafka", "--option", "1", "--bulk"])
-    assert rc == 0
-    err = capsys.readouterr().err
-    assert "not bulk-drainable" in err
-    assert "control-tuple stop" in err
+    for r in rows:
+        broker.produce(IN1, r)
+    assert main(["--config", cfg, "--kafka"] + argv) == 0
+    assert _marker_table(broker) == want != {}
+    assert broker.committed(IN1, "spatialflink") == len(rows)
 
 
 @pytest.mark.parametrize("opt,needs2", [

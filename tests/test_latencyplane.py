@@ -110,18 +110,19 @@ class TestStageBudget:
         assert sum(r["stages"]["queue"] for r in rows) > 0.0
 
     def test_bulk_payloads_skip_record_emit_but_feed_stages(self):
-        # bulk replay batches carry no per-record ingest stamps: the
-        # budget chain still feeds the stage histograms, but record→emit
-        # (whose definition needs the stamp) honestly records nothing
-        from spatialflink_tpu.streams.bulk import bulk_parse_csv
+        # records without an ingest stamp (ingestion_time 0, e.g. objects
+        # built outside any decode): the budget chain still feeds the
+        # stage histograms, but record→emit (whose definition needs the
+        # stamp) honestly records nothing
+        import dataclasses
 
-        data = "\n".join(_lines(5_000)).encode()
-        parsed = bulk_parse_csv(data, delimiter=",", schema=[0, 1, 2, 3])
+        recs = [dataclasses.replace(p, ingestion_time=0)
+                for p in driver.decode_stream(iter(_lines(5_000)), CFG, GRID)]
         conf = QueryConfiguration(QueryType.WindowBased, 10_000, 5_000)
         with scoped_registry(), telemetry_session() as tel:
             op = PointPointRangeQuery(conf, GRID)
             qp = Point.create(116.5, 40.3, GRID, obj_id="q")
-            out = list(op.run_bulk(parsed, qp, 0.5))
+            out = list(op.run(iter(recs), qp, 0.5))
             plane = tel.latency
         assert plane.windows == len(out) > 0
         assert plane.record_emit.count == 0

@@ -20,6 +20,7 @@ from spatialflink_tpu.ops.range import (
     range_filter_point_multi,
     range_filter_point_stats,
 )
+from tests import oracles as O
 
 GRID = UniformGrid(115.50, 117.60, 39.60, 41.10, num_grid_partitions=100)
 RADIUS = 0.5
@@ -592,8 +593,8 @@ class TestOperatorMulti:
         # YAML opt-in parses
         p = Params.from_yaml("conf/spatialflink-conf.yml")
         assert p.query.multi_query is False
-        # PointPoint cases ride the bulk multi evaluators; a non-PointPoint
-        # case declines to the record path (which dispatches or errors)
+        # the flag set in the config turns a CSV replay into multi-query
+        # windows on the served path
         p.query.multi_query = True
         p.query.option = 1
         src = tmp_path / "pts.csv"
@@ -602,10 +603,9 @@ class TestOperatorMulti:
         p = dataclasses.replace(
             p, input1=dataclasses.replace(p.input1, format="CSV"))
         p.input1.date_format = None
-        res = list(drv.run_option_bulk(p, str(src)))
+        with open(src) as f:
+            res = list(drv.run_option(p, f))
         assert res and res[0].extras["queries"] >= 1
-        p.query.option = 212  # trajectory kNN: record-path-only multi
-        assert drv.run_option_bulk(p, str(src)) is None
 
     def test_driver_multi_query_empty_list_errors(self):
         from spatialflink_tpu.config import Params
@@ -640,23 +640,27 @@ class TestOperatorMulti:
             assert parse_spatial(ln, "WKT").obj_id is not None
 
     def test_bulk_multi_query_matches_record_path(self, tmp_path):
-        """--bulk --multi-query: the vectorized replay answers the same
-        queries as the record path (kNN records identical; range counts
-        identical — bulk range emits original-record indices)."""
+        """--multi-query over a CSV replay (the columnar decode): every
+        query's per-window answer equals the oracle's (range: the records
+        within the radius; kNN at radius 0, no cell pruning: the exact
+        top-k)."""
         from spatialflink_tpu.config import Params
-        from spatialflink_tpu.driver import run_option, run_option_bulk
+        from spatialflink_tpu.driver import run_option
 
         rng = np.random.default_rng(17)
         t0 = 1_700_000_000_000
+        rows = [(f"v{i % 37}", t0 + i * 40,
+                 round(float(rng.uniform(116, 117)), 6),
+                 round(float(rng.uniform(40, 41)), 6)) for i in range(800)]
         src = tmp_path / "pts.csv"
-        src.write_text("\n".join(
-            f"v{i % 37},{t0 + i * 40},{rng.uniform(116, 117):.6f},"
-            f"{rng.uniform(40, 41):.6f}" for i in range(800)) + "\n")
+        src.write_text("\n".join(f"{o},{t},{x:.6f},{y:.6f}"
+                                 for o, t, x, y in rows) + "\n")
+        qpts = [(116.3, 40.3), (116.7, 40.7)]
 
         def params(option):
             p = Params.from_yaml("conf/spatialflink-conf.yml")
             p.query.option = option
-            p.query.radius = RADIUS
+            p.query.radius = RADIUS if option == 1 else 0.0
             p.query.k = K
             p.query.multi_query = True
             p.query.query_points = [(116.3, 40.3), (116.7, 40.7)]
@@ -667,18 +671,26 @@ class TestOperatorMulti:
             return p
 
         for option in (1, 51):
-            bulk = list(run_option_bulk(params(option), str(src)))
             with open(src) as f:
-                rec = list(run_option(params(option), f))
-            assert bulk and len(bulk) == len(rec), option
-            for b, r in zip(bulk, rec):
-                assert b.window_start == r.window_start
-                assert b.extras["queries"] == 2
-                if option == 51:
-                    assert b.records == r.records
-                else:
-                    assert [len(x) for x in b.records] == \
-                        [len(x) for x in r.records]
+                got = list(run_option(params(option), f))
+            assert got, option
+            for qi, (qx, qy) in enumerate(qpts):
+                if option == 1:
+                    want = O.range_window_table(rows, qx, qy, RADIUS,
+                                                10_000, 5_000)
+                    assert {w.window_start: sorted(
+                                (p.obj_id, p.timestamp)
+                                for p in w.records[qi])
+                            for w in got if w.records[qi]} == want, qi
+                    continue
+                want = O.knn_window_table(rows, qx, qy, K, 10_000, 5_000)
+                assert {w.window_start for w in got} == set(want)
+                for w in got:
+                    assert w.extras["queries"] == 2
+                    ids, dists = want[w.window_start]
+                    assert [o for o, _ in w.records[qi]] == ids, qi
+                    np.testing.assert_allclose(
+                        [d for _, d in w.records[qi]], dists, atol=1e-4)
 
     def test_tknn_run_multi_matches_run_loop(self):
         from spatialflink_tpu.operators import PointPointTKNNQuery
@@ -739,15 +751,16 @@ class TestOperatorMulti:
                                         ))
     def test_bulk_multi_geometry_cases_match_record_path(self, option,
                                                          tmp_path):
-        """The widened --bulk --multi-query matrix: geometry queries over
-        point streams and geometry streams ride the bulk evaluators and
-        agree with the record path (kNN records identical; range per-query
-        counts identical — bulk range emits original-record indices)."""
+        """The --multi-query matrix over geometry: geometry queries over
+        point streams and geometry streams, served from the raw text
+        (columnar decode for CSV points), agree with the same run over
+        per-record parsed objects."""
         import dataclasses
 
         from spatialflink_tpu.config import Params
-        from spatialflink_tpu.driver import CASES, run_option, run_option_bulk
-        from spatialflink_tpu.streams.formats import serialize_spatial
+        from spatialflink_tpu.driver import CASES, run_option
+        from spatialflink_tpu.streams.formats import (parse_spatial,
+                                                      serialize_spatial)
 
         spec = CASES[option]
         src = tmp_path / "stream.txt"
@@ -782,28 +795,32 @@ class TestOperatorMulti:
             p.input1.date_format = None
             return p
 
-        bulk = list(run_option_bulk(params(), str(src)))
         with open(src) as f:
-            rec = list(run_option(params(), f))
-        assert bulk and len(bulk) == len(rec), option
-        for b, r in zip(bulk, rec):
+            served = list(run_option(params(), f))
+        grid = params().grids()[0]
+        with open(src) as f:
+            objs = [parse_spatial(ln.rstrip("\n"), fmt, grid,
+                                  date_format=None,
+                                  geometry=spec.stream) for ln in f]
+        rec = list(run_option(params(), iter(objs)))
+        assert served and len(served) == len(rec), option
+        assert len({o.obj_id for o in objs}) == len(set(line_ids))
+        for b, r in zip(served, rec):
             assert b.window_start == r.window_start
             assert b.extras["queries"] == 2
             if spec.family == "knn":
                 # geometry queries produce mass ties at distance 0 (points
                 # INSIDE the polygon); top-k of ties has no canonical
-                # member set, and the bulk/record batch layouts break ties
+                # member set, and the two batch layouts break ties
                 # differently — distances must agree exactly, members only
                 # where untied
                 for bq, rq in zip(b.records, r.records):
                     assert [d for _, d in bq] == [d for _, d in rq], option
             else:
-                # bulk range emits original-record indices; map them back
-                # through the source lines and require per-query obj_id
-                # MULTISETS to match the record path (counts alone would
-                # pass a transposed mask)
+                # per-query obj_id MULTISETS (counts alone would pass a
+                # transposed mask)
                 for bq, rq in zip(b.records, r.records):
-                    assert sorted(line_ids[i] for i in bq) == \
+                    assert sorted(p.obj_id for p in bq) == \
                         sorted(p.obj_id for p in rq), option
 
     def test_cli_multi_query_flag(self, tmp_path, capsys):

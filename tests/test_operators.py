@@ -121,18 +121,13 @@ class TestRangePipeline:
                     GRID)
 
     def test_count_based_bulk_paths_refuse(self):
-        """Bulk replay assembles EVENT-TIME windows; under count mode its
-        window_spec() raises rather than silently reinterpreting counts as
+        """A WindowSpec is EVENT-TIME; under count mode window_spec()
+        raises rather than silently reinterpreting counts as
         milliseconds."""
         conf = QueryConfiguration(query_type=QueryType.CountBased,
                                   window_size_ms=40, slide_ms=15)
         with pytest.raises(NotImplementedError, match="record-path only"):
             conf.window_spec()
-        op = PointPointRangeQuery(conf, GRID)
-        with pytest.raises(NotImplementedError, match="record-path only"):
-            next(iter(op.run_multi_bulk(
-                __import__("types").SimpleNamespace(interner=None),
-                [QUERY], 0.3)))
 
     def test_incremental_matches_full(self):
         r = 0.3

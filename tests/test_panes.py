@@ -197,27 +197,52 @@ class TestFamilyEquivalence:
             assert off == on and off
 
     def test_bulk_range_and_knn(self):
-        from spatialflink_tpu.streams.bulk import bulk_parse_csv
+        # the served columnar decode of CSV text, panes on and off, against
+        # the oracle's window tables (kNN at radius 0: exact top-k)
+        from spatialflink_tpu.config import StreamConfig
+        from spatialflink_tpu.driver import decode_stream
 
         r = np.random.default_rng(11)
         n = 3000
         ts = 1_700_000_000_000 + np.sort(r.integers(0, 60_000, n))
-        lines = "".join(
-            f"v{int(o)},{t},{x:.6f},{y:.6f}\n"
-            for o, t, x, y in zip(r.integers(0, 50, n), ts,
-                                  r.uniform(115.6, 117.5, n),
-                                  r.uniform(39.7, 41.0, n)))
-        parsed = bulk_parse_csv(lines.encode(), date_format=None)
-        for cls, run in (
-                (PointPointRangeQuery,
-                 lambda op: op.run_bulk(parsed, QUERY, 0.4)),
-                (PointPointKNNQuery,
-                 lambda op: op.run_bulk(parsed, QUERY, 0.5, 7))):
-            off = [(r2.window_start, sorted(map(_canon_any, r2.records)))
-                   for r2 in run(cls(conf(False), GRID))]
-            on = [(r2.window_start, sorted(map(_canon_any, r2.records)))
-                  for r2 in run(cls(conf(True), GRID))]
-            assert off == on and off
+        rows = [(f"v{int(o)}", int(t), round(float(x), 6), round(float(y), 6))
+                for o, t, x, y in zip(r.integers(0, 50, n), ts,
+                                      r.uniform(115.6, 117.5, n),
+                                      r.uniform(39.7, 41.0, n))]
+        lines = [f"{o},{t},{x:.6f},{y:.6f}" for o, t, x, y in rows]
+        cfg = StreamConfig(format="CSV", date_format=None)
+
+        def served(panes, run):
+            return run(decode_stream(lines, cfg, GRID), panes)
+
+        def range_run(src, panes):
+            return {w.window_start: sorted((p.obj_id, p.timestamp)
+                                           for p in w.records)
+                    for w in PointPointRangeQuery(conf(panes), GRID).run(
+                        src, QUERY, 0.4) if w.records}
+
+        def knn_run(src, panes):
+            return {w.window_start: sorted(map(_canon_any, w.records))
+                    for w in PointPointKNNQuery(conf(panes), GRID).run(
+                        src, QUERY, 0.0, 7)}
+
+        want = O.range_window_table(rows, QUERY.x, QUERY.y, 0.4,
+                                    20_000, 5_000)
+        assert want
+        assert served(False, range_run) == want
+        assert served(True, range_run) == want
+        knn_want = {
+            start: sorted(zip(ids, (round(d, 6) for d in dists)))
+            for start, (ids, dists) in O.knn_window_table(
+                rows, QUERY.x, QUERY.y, 7, 20_000, 5_000).items()}
+        for panes in (False, True):
+            got = served(panes, knn_run)
+            assert got.keys() == knn_want.keys()
+            for start, recs in got.items():
+                assert [o for o, _ in recs] == [o for o, _ in knn_want[start]]
+                np.testing.assert_allclose([d for _, d in recs],
+                                           [d for _, d in knn_want[start]],
+                                           atol=1e-4)
 
     def test_tumbling_bypasses_cache(self):
         s = stream(seed=12)

@@ -472,9 +472,6 @@ def test_checkpoint_dir_gates(tmp_path, capsys):
     with pytest.raises(SystemExit):  # --resume without --checkpoint-dir
         main(["--config", cfg, "--option", "1", "--input1", path1,
               "--resume"])
-    with pytest.raises(SystemExit):  # --bulk does not compose
-        main(["--config", cfg, "--option", "1", "--input1", path1,
-              "--bulk", "--checkpoint-dir", str(tmp_path / "cp1")])
     with pytest.raises(SystemExit):  # legacy flag does not compose
         main(["--config", cfg, "--option", "205", "--input1", path1,
               "--checkpoint", str(tmp_path / "x.npz"),

@@ -69,7 +69,7 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
         ctypes.c_int, ctypes.c_int, ctypes.c_int, ctypes.c_int,
         _c_double_p, _c_double_p, _c_int64_p,
         _c_uint64_p, _c_int64_p, _c_int32_p,
-        _c_int64_p, _c_long_p,
+        _c_int64_p, _c_long_p, _c_long_p,
     ]
     lib.sf_parse_points_geojson.restype = ctypes.c_long
     lib.sf_parse_points_geojson.argtypes = [

@@ -16,7 +16,20 @@
 //   GeoJSON, malformed lines) are NOT errors: their line indices go to
 //   `rejects` and Python re-parses just those with the full-fidelity parser.
 //
-// Build: g++ -O3 -shared -fPIC (see native/__init__.py).
+// CSV number conversion is exact and mostly one pass. A coordinate field of
+// the shape [+-]?digits[.digits] with at most 15 significant digits and at
+// most 22 fraction digits is read as an integer mantissa m and a fraction
+// length f, and converted as (double)m / 10^f. Both operands are exact in
+// binary64 (m < 2^53, 10^f for f <= 22), so the one IEEE division rounds
+// the decimal's true value correctly: the same double strtod returns
+// (Clinger's fast path). Every other field (exponents, inf/nan, hex, longer
+// mantissas, surrounding spaces, anything malformed) goes to strtod as
+// before, so what is accepted and rejected does not change. A timestamp is
+// accumulated while its digits are checked; fields over 18 digits keep
+// strtoll, whose saturation on overflow stays the contract.
+//
+// Build: g++ -O3 -shared -fPIC (see native/__init__.py). Never with
+// -ffast-math: the fast path needs the division to be IEEE-exact.
 
 #include <cstdint>
 #include <cstdlib>
@@ -43,15 +56,58 @@ inline const char* rskip_ws(const char* begin, const char* p) {
     return p;
 }
 
+inline bool is_digit(char c) { return (unsigned char)(c - '0') <= 9; }
+
 // Parse an integer timestamp field. Digits-only, mirroring
 // formats.parse_timestamp (which passes `s.isdigit()` strings through as
 // ints and sends everything else — ISO dates, signs, floats — down the
 // strptime path); any other shape is rejected to Python.
 inline bool parse_int_field(const char* s, const char* end, int64_t* out) {
     if (s >= end) return false;
-    for (const char* p = s; p < end; p++)
-        if (*p < '0' || *p > '9') return false;
-    *out = (int64_t)strtoll(s, nullptr, 10);
+    uint64_t v = 0;
+    for (const char* p = s; p < end; p++) {
+        if (!is_digit(*p)) return false;
+        v = v * 10 + (uint64_t)(*p - '0');
+    }
+    // strtoll reads on past `end` while it sees digits (a digit delimiter)
+    // and saturates past int64: keep it wherever 18 digits are not the field
+    if (end - s > 18 || is_digit(*end))
+        v = (uint64_t)strtoll(s, nullptr, 10);
+    *out = (int64_t)v;
+    return true;
+}
+
+// Every power of ten an exact binary64 value: 10^22 < 2^53 * 2^22.
+constexpr double kPow10[] = {1e0,  1e1,  1e2,  1e3,  1e4,  1e5,  1e6,  1e7,
+                             1e8,  1e9,  1e10, 1e11, 1e12, 1e13, 1e14, 1e15,
+                             1e16, 1e17, 1e18, 1e19, 1e20, 1e21, 1e22};
+
+// The exact short-decimal path (see the header). False means "not this
+// shape", never "malformed": the caller then asks strtod.
+inline bool parse_short_decimal(const char* s, const char* end, double* out) {
+    const char* p = s;
+    bool neg = false;
+    if (p < end && (*p == '+' || *p == '-')) neg = *p++ == '-';
+    uint64_t m = 0;
+    int sig = 0, digits = 0, frac = 0;
+    for (; p < end && is_digit(*p); p++, digits++) {
+        m = m * 10 + (uint64_t)(*p - '0');
+        if (m && ++sig > 15) return false;
+    }
+    if (p < end && *p == '.') {
+        for (p++; p < end && is_digit(*p); p++, digits++, frac++) {
+            m = m * 10 + (uint64_t)(*p - '0');
+            if ((m && ++sig > 15) || frac == 22) return false;
+        }
+    }
+    // strtod reads past `end` while the number goes on (a delimiter that is
+    // a digit, '.', an exponent or a hex mark): leave such fields to it
+    char c = *end;
+    if (p != end || !digits || is_digit(c) || c == '.' || c == 'e' ||
+        c == 'E' || c == 'x' || c == 'X')
+        return false;
+    double v = (double)m / kPow10[frac];
+    *out = neg ? -v : v;
     return true;
 }
 
@@ -89,14 +145,26 @@ extern "C" {
 // appended to rejects (their 0-based line index); blank lines are skipped
 // entirely. Schema indices: oi (objID), ti (timestamp), xi, yi; oi/ti may be
 // -1 (absent). Capacity of all output arrays must be >= the line count.
+// n_float[0] / n_float[1] receive how many x and y fields the exact
+// short-decimal path converted / how many went to strtod.
 long sf_parse_points_csv(const char* buf, long len, char delim,
                          int oi, int ti, int xi, int yi,
                          double* xs, double* ys, int64_t* ts,
                          uint64_t* oid_hash, int64_t* oid_start,
                          int32_t* oid_len,
-                         int64_t* rejects, long* n_rejects) {
+                         int64_t* rejects, long* n_rejects, long* n_float) {
     long count = 0;
     long nrej = 0;
+    long n_fast = 0;
+    long n_slow = 0;
+    auto coord = [&](const Span& f, double* v) {
+        if (parse_short_decimal(f.start, f.end, v)) {
+            n_fast++;
+            return true;
+        }
+        n_slow++;
+        return parse_double_field(f.start, f.end, v);
+    };
     long line_idx = -1;
     const char* end = buf + len;
     const char* p = buf;
@@ -143,8 +211,7 @@ long sf_parse_points_csv(const char* buf, long len, char delim,
         }
 
         double x, y;
-        if (!parse_double_field(fields[xi].start, fields[xi].end, &x) ||
-            !parse_double_field(fields[yi].start, fields[yi].end, &y)) {
+        if (!coord(fields[xi], &x) || !coord(fields[yi], &y)) {
             rejects[nrej++] = line_idx;
             continue;
         }
@@ -196,6 +263,8 @@ long sf_parse_points_csv(const char* buf, long len, char delim,
         count++;
     }
     *n_rejects = nrej;
+    n_float[0] = n_fast;
+    n_float[1] = n_slow;
     return count;
 }
 

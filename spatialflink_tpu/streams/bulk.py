@@ -500,6 +500,7 @@ def bulk_parse_csv(
     ol = np.empty(cap, np.int32)
     rej = np.empty(cap, np.int64)
     nrej = ctypes.c_long(0)
+    nfloat = (ctypes.c_long * 2)()
     oi = -1 if schema[0] is None else int(schema[0])
     ti = -1 if schema[1] is None else int(schema[1])
     n = nlib.sf_parse_points_csv(
@@ -509,8 +510,13 @@ def bulk_parse_csv(
         _ptr(ts, ctypes.c_int64),
         _ptr(oh, ctypes.c_uint64), _ptr(os_, ctypes.c_int64),
         _ptr(ol, ctypes.c_int32),
-        _ptr(rej, ctypes.c_int64), ctypes.byref(nrej),
+        _ptr(rej, ctypes.c_int64), ctypes.byref(nrej), nfloat,
     )
+    # x and y fields (not records) by conversion: the exact short-decimal
+    # path, or strtod for every other shape
+    reg = _metrics.REGISTRY
+    reg.counter("csv-float-fast").inc(nfloat[0])
+    reg.counter("csv-float-slow").inc(nfloat[1])
     oid = _intern_hashes(data, oh[:n], os_[:n], ol[:n], interner, _NORM_CSV)
     accepted = {"x": xs[:n], "y": ys[:n], "ts": ts[:n], "oid": oid}
     reparsed = []
